@@ -28,6 +28,9 @@ the dry-run artifacts (artifacts/dryrun/*.json) when present.
   ``benchmarks/inference.py`` (when the ``inference`` figure is run).
 
 Usage:  PYTHONPATH=src python -m benchmarks.run [--json] [figure ...]
+
+A module that raises prints a ``<name>.FAILED`` row and the run goes on;
+the run then exits non-zero.
 """
 
 from __future__ import annotations
@@ -74,6 +77,7 @@ def main() -> None:
     explicit = [a for a in args if a != "--json"]
     wanted = explicit or list(mods)
     results: dict = {}
+    failed: list = []
     print("name,us_per_call,derived")
     for name in wanted:
         t0 = time.time()
@@ -84,6 +88,7 @@ def main() -> None:
             import traceback
             traceback.print_exc()
             print(f"{name}.FAILED,0,{type(e).__name__}:{str(e)[:120]}")
+            failed.append(name)
 
     if want_json:
         # a module that already failed above must not crash the JSON pass;
@@ -98,6 +103,7 @@ def main() -> None:
                     import traceback
                     traceback.print_exc()
                     print(f"# {name} failed — skipping its JSON artifact")
+                    failed.append(name)
         if "engine" in results:
             _write_json("BENCH_engine.json", results["engine"])
         if "shared" in results:
@@ -120,6 +126,8 @@ def main() -> None:
             }
             protocol["speedup_b8_p4"] = tp.get("speedup_b8_p4")
             _write_json("BENCH_protocol.json", protocol)
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

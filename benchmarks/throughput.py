@@ -135,6 +135,8 @@ def _run_jobs(serial: bool = False):
     workers = min(len(_JOBS), os.cpu_count() or 1)
     if workers <= 1:
         return [fn(arg) for fn, arg in _JOBS]
+    # the jobs never import JAX: a forked child must not need the chip
+    # that a parent which touched JAX holds
     ctx = mp.get_context("fork" if "fork" in mp.get_all_start_methods()
                          else "spawn")
     with ctx.Pool(workers) as pool:

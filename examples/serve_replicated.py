@@ -14,9 +14,8 @@ from repro.launch import serve as serve_mod
 
 
 def main() -> None:
-    sys.argv = ["serve", "--arch", "gemma3-1b", "--smoke",
-                "--requests", "12", "--batch", "4", "--gen", "6"]
-    serve_mod.main()
+    serve_mod.main(["--arch", "gemma3-1b", "--smoke",
+                    "--requests", "12", "--batch", "4", "--gen", "6"])
 
 
 if __name__ == "__main__":

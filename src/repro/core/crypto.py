@@ -636,24 +636,21 @@ def attest_batch(arrays: Sequence[Any], backend: str = "numpy") -> List[int]:
     """Attestation digests for a batch of word arrays.
 
     ``backend="numpy"`` runs the reference reduction; ``backend="pallas"``
-    runs ``repro.kernels.fingerprint.fingerprint_pallas`` (interpret mode
-    on CPU — the same kernel compiles for TPU), so accelerator
+    runs the fingerprint kernel through ``repro.kernels.ops.fingerprint``,
+    which compiles it on a TPU and interprets it elsewhere, so accelerator
     deployments hand the reduction to the data plane while the simulator
-    stays numpy-only.  Both backends produce identical uint32 digests
-    (parity-tested in tests/test_batch_engine.py)."""
+    stays numpy-only.  A ``jax.Array`` of words is digested where it
+    lives; anything else is converted to uint32 words on the host first.
+    Both backends produce identical uint32 digests (parity-tested in
+    tests/test_batch_engine.py)."""
     if backend == "numpy":
         return [attest_words_np(a) for a in arrays]
     if backend == "pallas":
-        from repro.kernels.fingerprint import fingerprint_pallas
-        import jax.numpy as jnp
-        out: List[int] = []
-        for a in arrays:
-            w = _np.asarray(a, dtype=_np.uint32).ravel()
-            if w.size == 0:
-                out.append(0)  # empty shard: sum of no words
-                continue
-            out.append(int(fingerprint_pallas(jnp.asarray(w))[0]))
-        return out
+        import jax
+        from repro.kernels.ops import fingerprint
+        return [int(fingerprint(
+            a if isinstance(a, jax.Array)
+            else _np.asarray(a, dtype=_np.uint32))[0]) for a in arrays]
     raise ValueError(f"unknown attest backend {backend!r}")
 
 

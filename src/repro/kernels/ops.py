@@ -82,15 +82,17 @@ def rglru_scan(a, x, *, t_blk: int = 128):
     return y[:, :S]
 
 
+def to_words(x):
+    """uint32 words of any array: 16-bit floats widen bit for bit, 32-bit
+    types bitcast, anything else converts."""
+    if x.dtype in (jnp.bfloat16, jnp.float16):
+        return jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+    if x.dtype in (jnp.float32, jnp.int32, jnp.uint32):
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return x.astype(jnp.uint32)
+
+
 @jax.jit
 def fingerprint(x):
     """uint32 digest of any array (bitcast to words first)."""
-    if x.dtype in (jnp.bfloat16, jnp.float16):
-        w = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
-    elif x.dtype == jnp.float32:
-        w = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    elif x.dtype in (jnp.int32, jnp.uint32):
-        w = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    else:
-        w = x.astype(jnp.uint32)
-    return fingerprint_pallas(w.reshape(-1), interpret=not _on_tpu())
+    return fingerprint_pallas(to_words(x).reshape(-1), interpret=not _on_tpu())

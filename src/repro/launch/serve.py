@@ -7,25 +7,52 @@ end-to-end driver — the paper's kind is SMR/serving).
 Three replicas hold the same model; client requests are ordered through
 uBFT consensus; the client accepts f+1 matching token streams, so a
 Byzantine replica cannot forge a generation.  Prints per-request latency:
-replication overhead is microseconds on top of model time.
+replication overhead is microseconds on top of model time.  The SMR
+latency is the simulator's virtual time; wall and compile seconds are the
+host clock around the real model work.
 """
 
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
-from repro.models.common import init_params
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.common import ModelConfig, init_params
 from repro.models.transformer import decode_step, prefill
 from repro.runtime.server import ReplicatedServer
 
 
-def main() -> None:
+@dataclass
+class ServeResult:
+    cfg: ModelConfig
+    params: Any
+    #: ``decode(session, hist, n) -> tokens``: the replicas' decode function
+    decode: Callable[[str, List[int], int], List[int]]
+    #: (session, prompt, n) per request, in submission order
+    requests: List[Tuple[str, List[int], int]]
+    #: reply tokens per request (None: shed by admission control)
+    tokens: List[Optional[List[int]]]
+    #: simulated µs from submission to the f+1-matched reply, per request
+    smr_latency_us: List[float]
+    #: replies the clients accepted on f+1 matching responses
+    matched: int
+    #: the 2f+1 replicas hold identical session state
+    replicas_identical: bool
+    #: host seconds for the request loop (compiles included)
+    wall_s: float
+    #: host seconds spent compiling prefill/decode programs
+    compile_s: float
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--smoke", action="store_true")
@@ -33,50 +60,89 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4, help="client sessions")
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=8)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run(args: argparse.Namespace) -> ServeResult:
+    """Serve ``args.requests`` greedy generations through 2f+1 replicas.
+
+    Sessions take turns; each session's first request carries a random
+    prompt (seeded), later ones continue the session with no prompt."""
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))
     max_seq = args.prompt_len + args.gen * args.requests + 8
 
     pf = jax.jit(lambda p, i: prefill(cfg, p, i, max_seq=max_seq))
     ds = jax.jit(lambda p, c, t, pos: decode_step(cfg, p, c, t, pos))
+    # compiled programs: prefill once per history length (ROADMAP S2), one
+    # decode step
+    execs: dict = {}
+    compile_s = 0.0
+
+    def compiled(key, jitted, *example):
+        nonlocal compile_s
+        exe = execs.get(key)
+        if exe is None:
+            t = time.perf_counter()
+            exe = execs[key] = jitted.lower(*example).compile()
+            compile_s += time.perf_counter() - t
+        return exe
 
     def decode_fn(session: str, hist, n: int):
         """Deterministic greedy decode of n tokens after `hist`."""
         toks = jnp.asarray([hist], jnp.int32)
-        logits, caches = pf(params, toks)
+        logits, caches = compiled(("prefill", len(hist)), pf,
+                                  params, toks)(params, toks)
         out = []
         pos = len(hist)
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
         for i in range(n):
             out.append(int(tok[0]))
-            logits, caches = ds(params, caches, tok, jnp.int32(pos + i))
+            p = jnp.int32(pos + i)
+            logits, caches = compiled("decode", ds, params, caches, tok,
+                                      p)(params, caches, tok, p)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
         return out
 
     server = ReplicatedServer.build(decode_fn)
     clients = [server.cluster.new_client() for _ in range(args.batch)]
     rng = np.random.default_rng(0)
-    lats = []
-    t0 = time.time()
+    requests, tokens, lats = [], [], []
+    t0 = time.perf_counter()
     for r in range(args.requests):
         cl = clients[r % len(clients)]
+        sid = f"s{r % len(clients)}"
         prompt = rng.integers(0, cfg.vocab, size=args.prompt_len).tolist() \
-            if r % len(clients) == r // len(clients) == 0 or True else []
-        toks, lat = server.generate(cl, f"s{r % len(clients)}",
-                                    prompt if r < len(clients) else [],
-                                    args.gen)
+            if r < len(clients) else []
+        toks, lat = server.generate(cl, sid, prompt, args.gen)
+        requests.append((sid, prompt, args.gen))
+        tokens.append(toks)
         lats.append(lat)
-        print(f"[req {r}] session=s{r % len(clients)} tokens={toks} "
+    wall_s = time.perf_counter() - t0
+    snaps = [r.app.snapshot() for r in server.cluster.replicas]
+    return ServeResult(
+        cfg=cfg, params=params, decode=decode_fn, requests=requests,
+        tokens=tokens, smr_latency_us=lats,
+        matched=sum(len(c.latencies) for c in clients),
+        replicas_identical=all(s == snaps[0] for s in snaps),
+        wall_s=wall_s, compile_s=compile_s)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    enable_compile_cache()
+    res = run(args)
+    for r, ((sid, _, _), toks, lat) in enumerate(
+            zip(res.requests, res.tokens, res.smr_latency_us)):
+        print(f"[req {r}] session={sid} tokens={toks} "
               f"smr_latency={lat:.1f}us")
-    lats = sorted(lats)
+    lats = sorted(res.smr_latency_us)
     print(f"\n{args.requests} requests, {args.batch} sessions | "
           f"SMR-ordering latency p50={lats[len(lats)//2]:.1f}us "
-          f"p90={lats[int(len(lats)*0.9)]:.1f}us | wall={time.time()-t0:.1f}s")
+          f"p90={lats[int(len(lats)*0.9)]:.1f}us | wall={res.wall_s:.1f}s "
+          f"compile={res.compile_s:.1f}s")
     # all replicas hold identical session state (BFT guarantee)
-    snaps = [r.app.snapshot() for r in server.cluster.replicas]
-    assert snaps[0] == snaps[1] == snaps[2]
+    assert res.replicas_identical
     print("replica state identical across 2f+1 replicas: OK")
 
 

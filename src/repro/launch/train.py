@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.configs import get_config, get_smoke_config
 from repro.data import DataConfig, TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import init_params, params_count
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.runtime.steps import make_train_step
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--byzantine", type=int, default=None,
                     help="index of a replica to corrupt (demo detection)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     pipe = TokenPipeline(DataConfig(vocab=cfg.vocab, seq_len=args.seq,

@@ -59,7 +59,6 @@ def _ffn_part(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
             y = moe_ffn(cfg, _moe_params(p), h)
         else:
             m = cfg.moe
-            from jax.experimental.shard_map import shard_map
             dp = ctx.dp_axes
             pspec_x = P(dp, None, None)
             especs = {
@@ -68,13 +67,13 @@ def _ffn_part(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
                 "w_up": P(ctx.tp_axis, None, None),
                 "w_down": P(ctx.tp_axis, None, None),
             }
-            fn = shard_map(
+            fn = jax.shard_map(
                 functools.partial(moe_ffn, cfg, axis_name=ctx.tp_axis,
                                   axis_size=ctx.tp_size),
                 mesh=ctx.mesh,
                 in_specs=(especs, pspec_x),
                 out_specs=pspec_x,
-                check_rep=False,
+                check_vma=False,
             )
             y = fn(_moe_params(p), h)
     else:

@@ -19,7 +19,6 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -64,12 +63,12 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
             jnp.where(stage == S - 1, outs, jnp.zeros_like(outs)), stage_axis)
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(stage_axis), stage_params,
                                is_leaf=lambda a: hasattr(a, "shape")),
                   P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)

@@ -126,13 +126,24 @@ def test_attest_batch_numpy_reference():
         assert g == acc
 
 
+#: words one grid step of the fingerprint kernel reads
+#: (``BLOCK_ROWS * LANES`` of repro.kernels.fingerprint; kept literal so
+#: collecting this file does not import JAX)
+_FP_BLOCK = 2048 * 128
+
+
 @pytest.mark.slow
-def test_attest_batch_pallas_parity():
+@pytest.mark.parametrize("sizes", [
+    (0,),                                   # empty array
+    (1, 5, 127, 1000),                      # under one (8, 128) tile
+    (128, 4096, 8192),                      # whole rows, under one block
+    (129, 4097, 10_000),                    # not a multiple of 128
+    (_FP_BLOCK, 2 * _FP_BLOCK + 77),        # one block; ragged last block
+], ids=["empty", "under_tile", "whole_rows", "ragged_rows", "multi_block"])
+def test_attest_batch_pallas_parity(sizes):
     pytest.importorskip("jax")
     rng = np.random.default_rng(7)
-    arrays = [rng.integers(0, 2**32, size=n, dtype=np.uint32)
-              for n in (1, 5, 4096, 4097, 10_000)] + \
-        [np.zeros(0, dtype=np.uint32)]
+    arrays = [rng.integers(0, 2**32, size=n, dtype=np.uint32) for n in sizes]
     assert crypto.attest_batch(arrays, backend="pallas") == \
         crypto.attest_batch(arrays, backend="numpy")
 

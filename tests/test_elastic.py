@@ -19,6 +19,7 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np, json
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models.common import init_params
     from repro.models.transformer import lm_loss
     from repro.checkpoint import save_checkpoint, load_checkpoint, reshard
@@ -34,7 +35,7 @@ SCRIPT = textwrap.dedent("""
 
     # "restart" on a different mesh: 2x4 instead of single-device
     step, p2, _ = load_checkpoint(ckpt_dir, expect_fp=fp)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = shard_ctx_for_mesh(mesh)
     pspecs = param_pspecs(cfg, p2, mesh)
     p_sharded = reshard(p2, mesh, pspecs)

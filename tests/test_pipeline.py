@@ -14,6 +14,7 @@ SCRIPT = textwrap.dedent("""
     import sys
     sys.path.insert(0, sys.argv[1])
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.parallel.pipeline import pipeline_apply
 
     S, n_micro, mb, d = 4, 8, 2, 16
@@ -24,7 +25,7 @@ SCRIPT = textwrap.dedent("""
     def stage_fn(w, h):
         return jnp.tanh(h @ w)
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     out = pipeline_apply(stage_fn, ws, x, mesh)
 
     ref = x

@@ -18,6 +18,7 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np, json
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models.common import init_params
     from repro.models.transformer import lm_loss
     from repro.parallel.sharding import (batch_pspecs, param_pspecs,
@@ -35,7 +36,7 @@ SCRIPT = textwrap.dedent("""
 
         loss_ref = float(jax.jit(lambda p: lm_loss(cfg, p, inputs, targets))(params))
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = shard_ctx_for_mesh(mesh)
         pspecs = param_pspecs(cfg, params, mesh)
         named = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
