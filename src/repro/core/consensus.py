@@ -33,6 +33,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional, Set, Tuple
 
+from repro import spans
 from repro.core import crypto
 from repro.core.crypto import SignedBundle
 from repro.core.ctbcast import CTBcast
@@ -1408,15 +1409,17 @@ class UbftReplica(Node):
                 self._pend_pop(rid)
                 self.echoes.pop(rid, None)
                 continue
-            result = self.app.apply_from(client, payload)
-            self.executed_rids.add(rid)
-            results.append(result)
-            self._pend_pop(rid)
-            self.echoes.pop(rid, None)
-            if client in self.sim.processes:
-                self.send(client, "REP", (rid, result))
-            for hook in self.on_execute_hooks:
-                hook(s, rid, payload, result)
+            with spans.span("replica.execute", replica=self.pid, slot=s,
+                            rid=rid):
+                result = self.app.apply_from(client, payload)
+                self.executed_rids.add(rid)
+                results.append(result)
+                self._pend_pop(rid)
+                self.echoes.pop(rid, None)
+                if client in self.sim.processes:
+                    self.send(client, "REP", (rid, result))
+                for hook in self.on_execute_hooks:
+                    hook(s, rid, payload, result)
         self.results[s] = tuple(results)
         self.exec_upto = s
 
@@ -1602,7 +1605,11 @@ class UbftReplica(Node):
     # ==================================================================
     def _maybe_checkpoint_round(self) -> None:
         last = self.checkpoint.open_slots[-1]
-        if self.exec_upto >= last:
+        if self.exec_upto < last:
+            return
+        spans.count("consensus.checkpoints")
+        with spans.span("consensus.checkpoint", replica=self.pid,
+                        slot=last + 1):
             # the boundary snapshot is the only one a signed checkpoint can
             # vouch for — retained (bounded) for joiner state transfer
             self._boundary_snaps[last + 1] = self.app.snapshot()

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs import get_config, get_smoke_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import ModelConfig, init_params
@@ -63,6 +64,13 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     return ap.parse_args(argv)
 
 
+def jit_as(name: str, fn: Callable) -> Callable:
+    """``jax.jit(fn)`` under ``name``: its HLO module, and the device
+    trace's ``XLA Modules`` line, read ``jit_<name>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 def run(args: argparse.Namespace) -> ServeResult:
     """Serve ``args.requests`` greedy generations through 2f+1 replicas.
 
@@ -72,8 +80,9 @@ def run(args: argparse.Namespace) -> ServeResult:
     params = init_params(cfg, jax.random.PRNGKey(0))
     max_seq = args.prompt_len + args.gen * args.requests + 8
 
-    pf = jax.jit(lambda p, i: prefill(cfg, p, i, max_seq=max_seq))
-    ds = jax.jit(lambda p, c, t, pos: decode_step(cfg, p, c, t, pos))
+    pf = jit_as("prefill", lambda p, i: prefill(cfg, p, i, max_seq=max_seq))
+    ds = jit_as("decode_step",
+                lambda p, c, t, pos: decode_step(cfg, p, c, t, pos))
     # compiled programs: prefill once per history length (ROADMAP S2), one
     # decode step
     execs: dict = {}
@@ -90,18 +99,24 @@ def run(args: argparse.Namespace) -> ServeResult:
 
     def decode_fn(session: str, hist, n: int):
         """Deterministic greedy decode of n tokens after `hist`."""
-        toks = jnp.asarray([hist], jnp.int32)
-        logits, caches = compiled(("prefill", len(hist)), pf,
-                                  params, toks)(params, toks)
+        with spans.span("serve.prefill", tokens=len(hist)):
+            toks = jnp.asarray([hist], jnp.int32)
+            logits, caches = compiled(("prefill", len(hist)), pf,
+                                      params, toks)(params, toks)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        spans.count("serve.prefills")
+        spans.count("serve.prefill_tokens", len(hist))
         out = []
         pos = len(hist)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
         for i in range(n):
-            out.append(int(tok[0]))
-            p = jnp.int32(pos + i)
-            logits, caches = compiled("decode", ds, params, caches, tok,
-                                      p)(params, caches, tok, p)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            with spans.span("serve.sync"):     # the host waits for the token
+                out.append(int(tok[0]))
+            with spans.span("serve.step"):
+                p = jnp.int32(pos + i)
+                logits, caches = compiled("decode", ds, params, caches, tok,
+                                          p)(params, caches, tok, p)
+                tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            spans.count("serve.decode_steps")
         return out
 
     server = ReplicatedServer.build(decode_fn)

@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.consensus import App, ConsensusConfig
 from repro.core.smr import Cluster, build_cluster
 
@@ -45,14 +46,16 @@ class TokenServerApp(App):
         self.sessions: Dict[str, List[int]] = {}
 
     def apply(self, req: bytes) -> bytes:
-        msg = json.loads(req.decode())
-        sid = msg["session"]
-        hist = self.sessions.setdefault(sid, [])
-        prompt = msg.get("prompt", [])
-        hist.extend(int(t) for t in prompt)
-        toks = self.decode_fn(sid, list(hist), int(msg.get("n", 1)))
-        hist.extend(int(t) for t in toks)
-        return json.dumps({"tokens": [int(t) for t in toks]}).encode()
+        with spans.span("app.apply") as sp:
+            msg = json.loads(req.decode())
+            sid = msg["session"]
+            sp.note(session=sid)
+            hist = self.sessions.setdefault(sid, [])
+            prompt = msg.get("prompt", [])
+            hist.extend(int(t) for t in prompt)
+            toks = self.decode_fn(sid, list(hist), int(msg.get("n", 1)))
+            hist.extend(int(t) for t in toks)
+            return json.dumps({"tokens": [int(t) for t in toks]}).encode()
 
     def cost_us(self, req: bytes) -> float:
         if self.cost_model is None:
@@ -68,7 +71,12 @@ class TokenServerApp(App):
         return float(self.cost_model.request_us(n_prompt, n_decode, ctx))
 
     def snapshot(self):
-        return tuple(sorted((k, tuple(v)) for k, v in self.sessions.items()))
+        # the table's size in token ids sets the checkpoint's cost
+        ids = (sum(map(len, self.sessions.values()))
+               if spans.recording() else None)
+        with spans.span("app.snapshot", ids=ids):
+            return tuple(sorted((k, tuple(v))
+                                for k, v in self.sessions.items()))
 
     def adopt(self, snap) -> None:
         self.sessions = {k: list(v) for k, v in snap}
