@@ -64,11 +64,28 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     return ap.parse_args(argv)
 
 
-def jit_as(name: str, fn: Callable) -> Callable:
-    """``jax.jit(fn)`` under ``name``: its HLO module, and the device
-    trace's ``XLA Modules`` line, read ``jit_<name>``."""
+def jit_as(name: str, fn: Callable, **jit_kw) -> Callable:
+    """``jax.jit(fn, **jit_kw)`` under ``name``: its HLO module, and the
+    device trace's ``XLA Modules`` line, read ``jit_<name>``."""
     fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+    return jax.jit(fn, **jit_kw)
+
+
+def greedy_tokens(cfg: ModelConfig, params, caches, tok: jax.Array,
+                  pos: jax.Array, n: int) -> jax.Array:
+    """The ``n`` greedy tokens that start with ``tok`` (int32[1], the
+    prefill's choice), decoded on the device: ``n - 1`` steps of
+    ``decode_step``, the k-th fed the token before it at position
+    ``pos + k - 1``.  Returns int32[n]."""
+    out = jnp.zeros((n,), jnp.int32).at[0].set(tok[0])
+
+    def body(i, carry):
+        caches, tok, out = carry
+        logits, caches = decode_step(cfg, params, caches, tok, pos + i - 1)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        return caches, tok, out.at[i].set(tok[0])
+
+    return jax.lax.fori_loop(1, n, body, (caches, tok, out))[2]
 
 
 def run(args: argparse.Namespace) -> ServeResult:
@@ -80,11 +97,16 @@ def run(args: argparse.Namespace) -> ServeResult:
     params = init_params(cfg, jax.random.PRNGKey(0))
     max_seq = args.prompt_len + args.gen * args.requests + 8
 
-    pf = jit_as("prefill", lambda p, i: prefill(cfg, p, i, max_seq=max_seq))
-    ds = jit_as("decode_step",
-                lambda p, c, t, pos: decode_step(cfg, p, c, t, pos))
-    # compiled programs: prefill once per history length (ROADMAP S2), one
-    # decode step
+    def first_token(p, ids):
+        logits, caches = prefill(cfg, p, ids, max_seq=max_seq)
+        return jnp.argmax(logits, -1).astype(jnp.int32), caches
+
+    pf = jit_as("prefill", first_token)
+    dl = jit_as("decode_loop",
+                lambda p, c, t, pos, n: greedy_tokens(cfg, p, c, t, pos, n),
+                static_argnums=4)
+    # compiled programs: prefill once per history length (ROADMAP S2), the
+    # decode loop once per number of tokens
     execs: dict = {}
     compile_s = 0.0
 
@@ -98,26 +120,25 @@ def run(args: argparse.Namespace) -> ServeResult:
         return exe
 
     def decode_fn(session: str, hist, n: int):
-        """Deterministic greedy decode of n tokens after `hist`."""
+        """Deterministic greedy decode of n tokens after `hist`: the prefill
+        picks the first, one loop on the device the other n - 1, and the
+        host reads them once."""
         with spans.span("serve.prefill", tokens=len(hist)):
-            toks = jnp.asarray([hist], jnp.int32)
-            logits, caches = compiled(("prefill", len(hist)), pf,
-                                      params, toks)(params, toks)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            ids = jnp.asarray([hist], jnp.int32)
+            toks, caches = compiled(("prefill", len(hist)), pf,
+                                    params, ids)(params, ids)
         spans.count("serve.prefills")
         spans.count("serve.prefill_tokens", len(hist))
-        out = []
-        pos = len(hist)
-        for i in range(n):
-            with spans.span("serve.sync"):     # the host waits for the token
-                out.append(int(tok[0]))
+        if n > 1:
             with spans.span("serve.step"):
-                p = jnp.int32(pos + i)
-                logits, caches = compiled("decode", ds, params, caches, tok,
-                                          p)(params, caches, tok, p)
-                tok = jnp.argmax(logits, -1).astype(jnp.int32)
-            spans.count("serve.decode_steps")
-        return out
+                pos = jnp.int32(len(hist))
+                toks = compiled(("decode_loop", n), dl, params, caches, toks,
+                                pos, n)(params, caches, toks, pos)
+            spans.count("serve.decode_loops")
+            spans.count("serve.decode_steps", n - 1)
+        with spans.span("serve.sync"):     # the host waits for the tokens
+            # [:n]: a request may ask for no token at all
+            return np.asarray(toks)[:n].tolist()
 
     server = ReplicatedServer.build(decode_fn)
     clients = [server.cluster.new_client() for _ in range(args.batch)]

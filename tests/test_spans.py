@@ -146,7 +146,9 @@ def test_recording_leaves_the_served_run_bit_identical(smoke_decode):
     assert {"replica.execute", "app.apply", "serve.prefill", "serve.step",
             "serve.sync", "consensus.checkpoint", "app.snapshot"} <= names
     assert rec.counters["serve.prefills"] == 30      # 10 requests, 3 replicas
-    assert rec.counters["serve.decode_steps"] == 60
+    # 2 tokens a request: the prefill's and one step of the decode loop
+    assert rec.counters["serve.decode_steps"] == 30
+    assert rec.counters["serve.decode_loops"] == 30
     assert rec.counters["consensus.checkpoints"] == 6   # slots 4 and 8
     assert spans.DROPPED not in rec.counters
 

@@ -100,6 +100,30 @@ def test_gemma3_serving_steps_fit_one_chip(one_chip, gemma3):
     assert _device_bytes(pf) < V5E_HBM_BYTES
 
 
+def test_gemma3_decode_loop_fits_one_chip(one_chip, gemma3):
+    """The token server's decode loop (``serve.greedy_tokens``), 8 tokens
+    as in chip_smoke's serving run: one loop on the device, not unrolled."""
+    from repro.launch.serve import greedy_tokens
+    cfg, shapes = gemma3
+    params = _placed(shapes, one_chip)
+    max_seq = 16 + 8 * 8 + 8
+    caches = _placed(jax.eval_shape(lambda: init_caches(cfg, 1, max_seq)),
+                     one_chip)
+    tok = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(lambda p, c, t, q: greedy_tokens(cfg, p, c, t, q, 8)
+                      ).lower(params, caches, tok, pos)
+    step = jax.jit(lambda p, c, t, q: decode_step(cfg, p, c, t, q)).lower(
+        params, caches, tok, pos)
+    # one loop around the step's own (its layer scans): 7 steps, one body
+    assert lowered.as_text().count("stablehlo.while") == \
+        step.as_text().count("stablehlo.while") + 1
+    compiled = lowered.compile()
+    # only the tokens leave the loop (one padded tile), not the caches
+    assert compiled.memory_analysis().output_size_in_bytes <= 4096
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
 def test_gemma3_sharded_loss_compiles_on_2x2(topo, gemma3):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
