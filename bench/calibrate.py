@@ -85,9 +85,8 @@ class Watchdog(threading.Thread):
 
 def check_weights(p) -> None:
     import numpy as np
-    from harness.reference import Weights
     params = p.serve.params
-    w = Weights(p.sizes)
+    w = p.family.Weights(p.sizes)
     pairs = [("embed", params["embed"], w.embed())]
     if "lm_head" in params:
         pairs.append(("lm_head", params["lm_head"], w.head()))
@@ -167,8 +166,8 @@ def main(argv=None) -> int:
             row_exact = dict(row)
             hist, start = runner.sample_histories(window, p.mix, seed)
             t = time.perf_counter()
-            gaps, ctl = served_gaps(p.sizes, hist, start, ctl_q,
-                                   shape=runner.reference_shape(p.mix))
+            gaps, ctl = served_gaps(p.family, p.sizes, hist, start, ctl_q,
+                                    shape=runner.reference_shape(p.mix))
             row.update({
                 "seed": seed, "tokens": int(gaps.size),
                 "sessions": len(hist), **check.gap_numbers(gaps),

@@ -9,6 +9,7 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -28,7 +29,10 @@ class NoChip(RuntimeError):
 @dataclass
 class Run:
     """What a metric's reader may read."""
-    sizes: models.Sizes
+    #: the configuration's sizes, as its family describes them
+    sizes: Any
+    #: the configuration's family (``bench/families/<family>.py``)
+    family: ModuleType
     replicas: int
     window: driver.Window
     setup_s: float
@@ -64,7 +68,8 @@ class Prepared:
     devices: List[Any]
     used: List[Any]
     peaks: Optional[Dict[str, float]]
-    sizes: models.Sizes
+    sizes: Any
+    family: ModuleType
     mix: traffic.Mix
     limits: Dict[str, Any]
     replicas: int
@@ -92,9 +97,9 @@ def prepare(workload: str, platform: str = "tpu", smoke: bool = False,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
     cfg_file = spec.config_file(bench, c["config"])
-    models.register(c["config"], cfg_file)
-    sizes = (models.smoke_sizes(c["config"], cfg_file) if smoke
-             else models.sizes(cfg_file))
+    family = spec.config_family(bench, c["config"])
+    models.register(c["config"], cfg_file, family)
+    sizes = family.sizes(cfg_file, smoke)
     mix = traffic.load(c["traffic"])
     counter = driver.CompileCounter()
     t = time.perf_counter()
@@ -103,7 +108,8 @@ def prepare(workload: str, platform: str = "tpu", smoke: bool = False,
         f"(compile {res.compile_s:.3f} s, its own session "
         f"{res.wall_s:.3f} s); compilations so far {counter.count}")
     return Prepared(bench=bench, cell=c, devices=devs, used=used, peaks=pk,
-                    sizes=sizes, mix=mix, limits=spec.limits_file(workload),
+                    sizes=sizes, family=family, mix=mix,
+                    limits=spec.limits_file(workload),
                     replicas=int(cfg_file["guarantees"]["replicas"]),
                     serve=res, counter=counter)
 
@@ -184,12 +190,12 @@ def execute(workload: str, seed: int, seconds: float, trace: bool,
     del snapshots
     gc.collect()
     t = time.perf_counter()
-    values.update(check.gap_numbers(served_token_gaps(window, p.mix, p.sizes,
-                                                     seed)))
+    values.update(check.gap_numbers(served_token_gaps(
+        window, p.mix, p.family, p.sizes, seed)))
     log(f"reference: {time.perf_counter() - t:.3f} s")
     correct, numbers = check.judge(values, p.limits)
 
-    run = Run(sizes=p.sizes, replicas=p.replicas,
+    run = Run(sizes=p.sizes, family=p.family, replicas=p.replicas,
               window=window, setup_s=setup_s, peaks=p.peaks, trace=reduced)
     metrics = {}
     for m in spec.metrics_for(p.bench, workload,
@@ -231,12 +237,14 @@ def sample_histories(window: driver.Window, mix: traffic.Mix, seed: int):
 
 
 def served_token_gaps(window: driver.Window, mix: traffic.Mix,
-                      sizes: models.Sizes, seed: int) -> np.ndarray:
+                      family: ModuleType, sizes: Any, seed: int
+                      ) -> np.ndarray:
     """The gap of every served token of the sampled sessions."""
     hist, start = sample_histories(window, mix, seed)
     if not hist:
         return np.zeros(0)
-    gaps, _ = served_gaps(sizes, hist, start, shape=reference_shape(mix))
+    gaps, _ = served_gaps(family, sizes, hist, start,
+                          shape=reference_shape(mix))
     return gaps
 
 

@@ -8,8 +8,11 @@ own, found by name:
 * ``bench/traffic/<traffic>.json``  the traffic mix's parameters
 * ``bench/limits/<cell>.json``      the limits ``correct`` is decided by
 * ``bench/metrics/<metric>.py``     the reader of one metric
+* ``bench/families/<family>.py``    a model family (a configuration's
+  ``program.family``): its sizes, the program's config, its FLOP count
+  and the plain reference's layer
 
-so a later cell, mix or metric adds files and edits none.
+so a later cell, mix, metric or family adds files and edits none.
 """
 
 from __future__ import annotations
@@ -17,6 +20,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import sys
 from types import ModuleType
 from typing import Any, Dict, List
 
@@ -73,14 +77,40 @@ def metrics_for(bench: Dict[str, Any], cell_name: str, kind: str
             if cell_name in m.get("workloads", [cell_name])]
 
 
+def _load(path: str, prefix: str, name: str) -> ModuleType:
+    mod_name = prefix + "".join(c if c.isalnum() else "_" for c in name)
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod      # where dataclasses look up its names
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str) -> ModuleType:
     """``bench/metrics/<name>.py``, which defines ``read(run)``."""
     path = os.path.join(BENCH, "metrics", f"{name}.py")
     if not os.path.exists(path):
         raise SpecError(f"no reader {path} for metric {name!r}")
-    mod_name = "bench_metric_" + "".join(
-        c if c.isalnum() else "_" for c in name)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load(path, "bench_metric_", name)
+
+
+def family(name: str) -> ModuleType:
+    """``bench/families/<name>.py``, which defines ``Sizes``,
+    ``program_config(name, cfg_file, smoke)``, ``sizes(cfg_file, smoke)``,
+    ``request_flops(sizes, processed, history, n)``, ``Weights(sizes)``
+    (``embed()``, ``head()``, ``layer(i)``) and ``layer(x, weights, sizes,
+    quant)``."""
+    path = os.path.join(BENCH, "families", f"{name}.py")
+    if not os.path.exists(path):
+        raise SpecError(f"no family {path} for family {name!r}")
+    return _load(path, "bench_family_", name)
+
+
+def config_family(bench: Dict[str, Any], name: str) -> ModuleType:
+    """The family configuration ``name``'s file names under
+    ``program.family``."""
+    fam = config_file(bench, name).get("program", {}).get("family")
+    if fam is None:
+        path = config_entry(bench, name)["file"]
+        raise SpecError(f"{path} names no program.family")
+    return family(fam)

@@ -66,8 +66,8 @@ def traced_window(p: runner.Prepared, seed: int, seconds: float,
     tr = program.read(path)
     shutil.rmtree(window.trace_dir, ignore_errors=True)
     clipped = program.clip(rec.spans, window.t0, window.t_close)
-    run = SpanRun(sizes=p.sizes, replicas=p.replicas, window=window,
-                  setup_s=window.t0 - t_start, peaks=p.peaks,
+    run = SpanRun(sizes=p.sizes, family=p.family, replicas=p.replicas,
+                  window=window, setup_s=window.t0 - t_start, peaks=p.peaks,
                   trace=xtrace.reduce(tr.events), spans=clipped,
                   modules=program.module_times(tr))
     for line in program.describe(clipped, rec.counters, window.t0):

@@ -28,7 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 BENCH = os.path.join(ROOT, "bench")
 sys.path[:0] = [BENCH, os.path.join(ROOT, "src")]
 
-from harness import check, driver, flops, models, runner, spec, traffic, xtrace  # noqa: E402,E501
+from harness import check, driver, runner, spec, traffic, xtrace  # noqa: E402
 from harness.reference import served_gaps  # noqa: E402
 
 TRACE = os.path.join(HERE, "data", "chatglm3-6b-d14.chat.trace.txtpb")
@@ -97,18 +97,22 @@ def test_trace_with_no_device_reads_no_idle_share():
 # step_mfu's FLOP count
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def glm_smoke(bench):
-    name = "chatglm3-6b-d14"
-    return models.smoke_sizes(name, spec.config_file(bench, name))
+def dense():
+    return spec.family("dense_gqa")
 
 
-def test_flop_count_matches_hand_count(glm_smoke):
+@pytest.fixture(scope="module")
+def glm_smoke(bench, dense):
+    return dense.sizes(spec.config_file(bench, "chatglm3-6b-d14"), smoke=True)
+
+
+def test_flop_count_matches_hand_count(dense, glm_smoke):
     s = glm_smoke
     assert (s.layers, s.d_model, s.heads, s.kv_heads, s.head_dim, s.d_ff,
             s.vocab) == (2, 64, 4, 2, 16, 128, 256)
     # q 64x64, k and v 64x32 each, o 64x64, gate/up/down 64x128 each
     weights = 64 * 64 + 2 * 64 * 32 + 64 * 64 + 3 * 64 * 128
-    assert flops.layer_weights(s) == weights == 36864
+    assert weights == 36864
 
     def by_hand(processed, history, n):
         total = 0
@@ -117,19 +121,19 @@ def test_flop_count_matches_hand_count(glm_smoke):
         return total + n * 2 * 64 * 256
 
     for args in [(0, 128, 32), (159, 160, 32), (0, 448, 1), (3, 4, 1)]:
-        assert flops.request_flops(s, *args) == by_hand(*args)
+        assert dense.request_flops(s, *args) == by_hand(*args)
 
 
-def test_step_mfu_counts_every_replica_and_stays_under_peak(glm_smoke):
+def test_step_mfu_counts_every_replica_and_stays_under_peak(dense, glm_smoke):
     reader = spec.metric_reader("step_mfu")
     req = lambda p, h, n: SimpleNamespace(  # noqa: E731
         processed=p, history=h, tokens=[0] * n, in_window=True)
     w = SimpleNamespace(done=lambda: [req(0, 128, 32), req(159, 160, 32)],
                         seconds=2.0, profiler_s=0.0)
-    run = SimpleNamespace(window=w, sizes=glm_smoke, replicas=3,
+    run = SimpleNamespace(window=w, sizes=glm_smoke, family=dense, replicas=3,
                           peaks={"bf16_flops_per_s": 1e9})
-    want = 100 * 3 * (flops.request_flops(glm_smoke, 0, 128, 32)
-                      + flops.request_flops(glm_smoke, 159, 160, 32)) / 2e9
+    want = 100 * 3 * (dense.request_flops(glm_smoke, 0, 128, 32)
+                      + dense.request_flops(glm_smoke, 159, 160, 32)) / 2e9
     assert reader.read(run) == pytest.approx(want)
     run.peaks = None
     assert reader.read(run) is None
@@ -184,7 +188,8 @@ def test_benchmark_names_and_files_are_found(bench):
         assert f["name"] == cfg["name"] and f["reduced"] == cfg["reduced"]
         assert set(f["reduced"]) <= set(f["config"]) and \
             set(f["reduced"]) == set(f["published"])
-        models.model_config(cfg["name"], f).validate()
+        spec.config_family(bench, cfg["name"]).program_config(
+            cfg["name"], f).validate()
     for c in bench["workloads"]:
         assert NAME.match(c["name"]) and c["chips"] in (1, 4)
         spec.config_entry(bench, c["config"])
@@ -306,7 +311,7 @@ def test_control_in_fp8_is_not_correct(monkeypatch):
     for seed in (2**31 + 3, 2**33 + 5):
         window, _, _ = runner.measure(p, seed, 3.0, False, log=lambda _: None)
         hist, start = runner.sample_histories(window, p.mix, seed)
-        gaps, ctl = served_gaps(p.sizes, hist, start, ["fp8"],
+        gaps, ctl = served_gaps(p.family, p.sizes, hist, start, ["fp8"],
                                 shape=runner.reference_shape(p.mix))
         program.append(gaps.max())
         control.append(ctl["fp8"].max())

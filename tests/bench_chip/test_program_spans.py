@@ -69,7 +69,10 @@ def test_every_execution_holds_one_apply_and_one_prefill(traced):
         kids = _children(spans, i)
         assert [spans[j][0] for j in kids] == ["app.apply"]
         inner = [spans[j][0] for j in _children(spans, kids[0])]
-        assert inner == ["serve.prefill"] + ["serve.sync", "serve.step"] * n
+        # the prefill, one decode loop for the n - 1 tokens after its own,
+        # and one read of all n
+        loop = ["serve.step"] if n > 1 else []
+        assert inner == ["serve.prefill"] + loop + ["serve.sync"]
         ids = spans[i][4]
         assert ids["replica"] in {"r0", "r1", "r2"} and ids["slot"] >= 0
     # the spans of one request share its rid, once per replica
@@ -85,12 +88,19 @@ def test_counters_match_the_harness_requests(traced):
     assert len(answered) == len(w.requests)
     reps = traced.p.replicas
     assert c["serve.prefills"] == w.executions == reps * len(answered)
-    assert c["serve.decode_steps"] == reps * sum(len(r.tokens)
-                                                 for r in answered)
+    steps = reps * sum(len(r.tokens) - 1 for r in answered)
+    loops = reps * sum(len(r.tokens) > 1 for r in answered)
+    assert c.get("serve.decode_steps", 0) == steps
+    assert c.get("serve.decode_loops", 0) == loops
+    # a counter never counted is absent, not 0
+    assert ("serve.decode_steps" in c) == ("serve.decode_loops" in c) \
+        == (steps > 0) == (traced.p.mix.output_tokens > 1)
     assert c["serve.prefill_tokens"] == reps * sum(r.history
                                                    for r in answered)
-    assert any(line.startswith("counters: serve.decode_steps")
-               for line in traced.lines)
+    (line,) = [ln for ln in traced.lines if ln.startswith("counters: ")]
+    names = [x.split()[0] for x in line[len("counters: "):].split(", ")]
+    assert names == sorted(c)
+    assert ("serve.decode_steps" in names) == (steps > 0)
 
 
 def test_host_and_wait_add_up_to_the_harness_timer(traced):
@@ -152,8 +162,8 @@ def test_checkpoints_are_counted_sized_and_timed():
 @pytest.mark.parametrize("metric", trace_spans.SPAN_METRICS)
 def test_readers_read_nothing_from_a_run_without_spans(metric):
     w = SimpleNamespace(done=lambda: [SimpleNamespace(tokens=[1])])
-    plain = runner.Run(sizes=None, replicas=3, window=w, setup_s=1.0,
-                       peaks=None)
+    plain = runner.Run(sizes=None, family=None, replicas=3, window=w,
+                       setup_s=1.0, peaks=None)
     assert spec.metric_reader(metric).read(plain) is None
 
 
