@@ -1,5 +1,5 @@
 """Qwen3-MoE 235B (22B active) — 128 experts, top-8, GQA kv=4, qk-norm.
-[hf:Qwen/Qwen3-30B-A3B; hf]
+[hf:Qwen/Qwen3-235B-A22B; hf]
 
 Exact assigned configuration (see DESIGN.md §6); ``smoke_config`` is the
 reduced same-family config used by the CPU smoke tests.
@@ -16,7 +16,7 @@ def config() -> ModelConfig:
         blocks=default_blocks(94),
         moe=MoEConfig(n_experts=128, top_k=8, d_expert=1536,
                       capacity_factor=1.25),
-        qk_norm=True, rope_theta=1_000_000.0,
+        qk_norm=True, rope_theta=1_000_000.0, tie_embeddings=False,
     )
 
 

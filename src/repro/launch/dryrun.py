@@ -110,7 +110,8 @@ def _body_cost(cfg: ModelConfig, ctx, mesh, kind: str, gi: int,
         def fn(x, positions, gp):
             outs = []
             for spec, p in zip(pattern, gp):
-                x, st = apply_layer_prefill(cfg, spec, p, x, positions, S, ctx)
+                x, st, _ = apply_layer_prefill(cfg, spec, p, x, positions, S,
+                                               ctx)
                 outs.append(st)
             return x, tuple(outs)
 
@@ -121,7 +122,8 @@ def _body_cost(cfg: ModelConfig, ctx, mesh, kind: str, gi: int,
         def fn(x, gp, gc, position):
             outs = []
             for spec, p, c in zip(pattern, gp, gc):
-                x, nc = apply_layer_decode(cfg, spec, p, x, c, position, ctx)
+                x, nc, _ = apply_layer_decode(cfg, spec, p, x, c, position,
+                                              ctx)
                 outs.append(nc)
             return x, tuple(outs)
 

@@ -27,6 +27,7 @@ from repro import spans
 from repro.configs import get_config, get_smoke_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import ModelConfig, init_params
+from repro.models.moe import COUNTERS, add_counts
 from repro.models.transformer import decode_step, prefill
 from repro.runtime.server import ReplicatedServer
 
@@ -72,20 +73,23 @@ def jit_as(name: str, fn: Callable, **jit_kw) -> Callable:
 
 
 def greedy_tokens(cfg: ModelConfig, params, caches, tok: jax.Array,
-                  pos: jax.Array, n: int) -> jax.Array:
+                  pos: jax.Array, n: int) -> Tuple[jax.Array, Any]:
     """The ``n`` greedy tokens that start with ``tok`` (int32[1], the
     prefill's choice), decoded on the device: ``n - 1`` steps of
     ``decode_step``, the k-th fed the token before it at position
-    ``pos + k - 1``.  Returns int32[n]."""
+    ``pos + k - 1``.  Returns int32[n] and the MoE counters summed over the
+    steps (None for a model without held experts)."""
     out = jnp.zeros((n,), jnp.int32).at[0].set(tok[0])
+    counts = None if cfg.moe is None else jnp.zeros(len(COUNTERS), jnp.int32)
 
     def body(i, carry):
-        caches, tok, out = carry
-        logits, caches = decode_step(cfg, params, caches, tok, pos + i - 1)
+        caches, tok, out, counts = carry
+        logits, caches, k = decode_step(cfg, params, caches, tok,
+                                        pos + i - 1, counts=True)
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        return caches, tok, out.at[i].set(tok[0])
+        return caches, tok, out.at[i].set(tok[0]), add_counts(counts, k)
 
-    return jax.lax.fori_loop(1, n, body, (caches, tok, out))[2]
+    return jax.lax.fori_loop(1, n, body, (caches, tok, out, counts))[2:]
 
 
 def run(args: argparse.Namespace) -> ServeResult:
@@ -98,8 +102,13 @@ def run(args: argparse.Namespace) -> ServeResult:
     max_seq = args.prompt_len + args.gen * args.requests + 8
 
     def first_token(p, ids):
-        logits, caches = prefill(cfg, p, ids, max_seq=max_seq)
-        return jnp.argmax(logits, -1).astype(jnp.int32), caches
+        counts = None
+        if cfg.moe is None:
+            logits, caches = prefill(cfg, p, ids, max_seq=max_seq)
+        else:
+            logits, caches, counts = prefill(cfg, p, ids, max_seq=max_seq,
+                                             counts=True)
+        return jnp.argmax(logits, -1).astype(jnp.int32), caches, counts
 
     pf = jit_as("prefill", first_token)
     dl = jit_as("decode_loop",
@@ -122,21 +131,28 @@ def run(args: argparse.Namespace) -> ServeResult:
     def decode_fn(session: str, hist, n: int):
         """Deterministic greedy decode of n tokens after `hist`: the prefill
         picks the first, one loop on the device the other n - 1, and the
-        host reads them once."""
+        host reads them once (with a MoE model's counters, while spans are
+        recorded)."""
         with spans.span("serve.prefill", tokens=len(hist)):
             ids = jnp.asarray([hist], jnp.int32)
-            toks, caches = compiled(("prefill", len(hist)), pf,
-                                    params, ids)(params, ids)
+            toks, caches, counts = compiled(("prefill", len(hist)), pf,
+                                            params, ids)(params, ids)
         spans.count("serve.prefills")
         spans.count("serve.prefill_tokens", len(hist))
         if n > 1:
             with spans.span("serve.step"):
                 pos = jnp.int32(len(hist))
-                toks = compiled(("decode_loop", n), dl, params, caches, toks,
-                                pos, n)(params, caches, toks, pos)
+                toks, more = compiled(("decode_loop", n), dl, params, caches,
+                                      toks, pos, n)(params, caches, toks, pos)
+            counts = (counts, more)
             spans.count("serve.decode_loops")
             spans.count("serve.decode_steps", n - 1)
         with spans.span("serve.sync"):     # the host waits for the tokens
+            if cfg.moe is not None and spans.recording():
+                toks, counts = jax.device_get((toks, counts))
+                for name, k in zip(COUNTERS,
+                                   np.sum(jax.tree.leaves(counts), axis=0)):
+                    spans.count(name, int(k))
             # [:n]: a request may ask for no token at all
             return np.asarray(toks)[:n].tolist()
 

@@ -34,11 +34,18 @@ class LayerSpec:
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int              # routed experts: the router's width
     top_k: int
     d_expert: int
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # mesh path only (repro.models.moe)
     router_dtype: str = "float32"
+    #: (first, count): the experts this device holds, one expert-parallel
+    #: share of the layer; None holds all ``n_experts``
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held or (0, self.n_experts)
 
 
 @dataclass(frozen=True)
@@ -198,11 +205,20 @@ def init_layer_params(cfg: ModelConfig, spec: LayerSpec, key) -> Dict[str, Any]:
         p["ln2"] = jnp.zeros((D,), dt)
         if cfg.moe is not None:
             m = cfg.moe
+            F = m.d_expert
             p["router"] = _init(ks[8], (D, m.n_experts), s_in, jnp.float32)
-            p["w_gate"] = _init(ks[9], (m.n_experts, D, m.d_expert), s_in, dt)
-            p["w_up"] = _init(ks[10], (m.n_experts, D, m.d_expert), s_in, dt)
-            p["w_down"] = _init(ks[11], (m.n_experts, m.d_expert, D),
-                                m.d_expert ** -0.5, dt)
+            # only the held experts, each from its own key (its index folded
+            # into the matrix's), so a share's experts are a slice of the
+            # whole layer's
+            e0, n = m.held_range
+            experts = jnp.arange(e0, e0 + n)
+
+            def draw(k, shape, scale):
+                keys = jax.vmap(lambda e: jax.random.fold_in(k, e))(experts)
+                return jax.vmap(lambda ke: _init(ke, shape, scale, dt))(keys)
+            p["w_gate"] = draw(ks[9], (D, F), s_in)
+            p["w_up"] = draw(ks[10], (D, F), s_in)
+            p["w_down"] = draw(ks[11], (F, D), F ** -0.5)
         else:
             p["w_gate"] = _init(ks[9], (D, cfg.d_ff), s_in, dt)
             p["w_up"] = _init(ks[10], (D, cfg.d_ff), s_in, dt)

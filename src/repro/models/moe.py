@@ -1,7 +1,17 @@
-"""Mixture-of-Experts FFN with expert parallelism.
+"""Mixture-of-Experts FFN: the experts one device holds, and expert
+parallelism over a mesh.
 
-Strategy (default, "EP-as-TP"): activations are replicated across the
-``model`` mesh axis (as they already are for tensor parallelism); experts are
+Without a mesh (:func:`held_moe_ffn`) the layer holds the experts
+``cfg.moe.held`` names, one expert-parallel share of the layer, or all of
+them.  It routes every token over all ``n_experts``, runs every held
+expert on every token (a capacity of T rows an expert, so no token is ever
+dropped) and weights each output by the token's routing weight, which is 0
+where the token is not routed to that expert.  What absent experts would
+add is left to the devices that hold them; nothing here stands in for
+them.  It also returns the counts of :data:`COUNTERS`.
+
+On a mesh (:func:`moe_ffn`), strategy "EP-as-TP": activations are
+replicated across the ``model`` mesh axis (as they already are for tensor parallelism); experts are
 sharded across it.  Every device routes the full local token set, computes
 *its* experts' contributions through a sort-based fixed-capacity dispatch
 (no (T, E, C) one-hot — O(T·k) memory), and the contributions are combined
@@ -10,8 +20,8 @@ this moves strictly fewer bytes than a token all-to-all (2·D vs k·D per
 token) and composes with XLA's collective fusion; the all-to-all variant is
 kept as a hillclimb alternative (see EXPERIMENTS.md §Perf).
 
-The routed computation is ragged; we use fixed per-expert capacity
-C = max(min_cap, ceil(T·k/E · capacity_factor)) with token dropping
+The mesh path's routed computation is ragged; it uses fixed per-expert
+capacity C = max(min_cap, ceil(T·k/E · capacity_factor)) with token dropping
 (standard dropping MoE), realized with scatter(mode="drop") /
 gather(mode="fill").
 """
@@ -26,6 +36,22 @@ import jax.numpy as jnp
 
 from repro.models.common import ModelConfig
 
+#: what :func:`held_moe_ffn` counts, in the order of its counts vector:
+#: (token, expert) pairs routed; of those, pairs on a held expert; held
+#: pairs that got no row (0: the layer is dropless); held experts that got
+#: at least one token
+COUNTERS = ("moe.routed_pairs", "moe.held_pairs", "moe.dropped",
+            "moe.held_experts_hit")
+#: the router's matmul precision: float32 logits, where a TPU's default
+#: for float32 operands is one bfloat16 pass, which can flip near-ties
+ROUTER_PRECISION = "highest"
+
+
+def add_counts(a: Optional[jax.Array], b: Optional[jax.Array]
+               ) -> Optional[jax.Array]:
+    """Two sets of counters summed, where there are any (None: none)."""
+    return b if a is None else a if b is None else a + b
+
 
 def _capacity(T: int, k: int, E: int, cf: float) -> int:
     return max(min(T, 32), int(math.ceil(T * k / E * cf)))
@@ -33,9 +59,14 @@ def _capacity(T: int, k: int, E: int, cf: float) -> int:
 
 def route(cfg: ModelConfig, router_w: jax.Array, x: jax.Array
           ) -> Tuple[jax.Array, jax.Array]:
-    """x: (T, D) -> (weights (T,k), experts (T,k)); deterministic."""
+    """x: (T, D) -> (weights (T,k), experts (T,k)); deterministic.
+
+    Logits in float32 at ``ROUTER_PRECISION``; the softmax runs over the
+    top-k logits alone, which is the full softmax renormalised over the
+    chosen k."""
     m = cfg.moe
-    logits = x.astype(jnp.float32) @ router_w          # (T, E)
+    logits = jnp.matmul(x.astype(jnp.float32), router_w,
+                        precision=ROUTER_PRECISION)    # (T, E)
     top_w, top_e = jax.lax.top_k(logits, m.top_k)
     top_w = jax.nn.softmax(top_w, axis=-1)
     return top_w, top_e
@@ -87,28 +118,44 @@ def moe_ffn_local(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
     return out
 
 
+def held_moe_ffn(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the layer over tokens x (T, D).
+
+    ``p``'s expert tensors hold the ``n`` experts of ``cfg.moe.held_range``
+    (n, D, F)/(n, F, D).  Returns that part (T, D) and the int32 counts of
+    :data:`COUNTERS`."""
+    m = cfg.moe
+    e0, n = m.held_range
+    T = x.shape[0]
+    with jax.named_scope("moe.route"):
+        top_w, top_e = route(cfg, p["router"], x)                # (T, k)
+        hit = top_e[..., None] == e0 + jnp.arange(n)             # (T, k, n)
+        gates = jnp.sum(jnp.where(hit, top_w[..., None], 0.0), axis=1)
+        rows = jnp.sum(hit, axis=(0, 1))                 # pairs per expert
+    with jax.named_scope("moe.experts"):
+        h = jnp.einsum("td,edf->etf", x, p["w_gate"])
+        u = jnp.einsum("td,edf->etf", x, p["w_up"])
+        y = jnp.einsum("etf,efd->etd", jax.nn.silu(h) * u, p["w_down"])
+        out = jnp.einsum("te,etd->td", gates.astype(x.dtype), y)
+    counts = jnp.stack([jnp.int32(T * m.top_k), jnp.sum(rows),
+                        jnp.sum(jnp.maximum(rows - T, 0)),
+                        jnp.sum(rows > 0)]).astype(jnp.int32)
+    return out, counts
+
+
 def dense_ffn(p: Dict[str, Any], x: jax.Array) -> jax.Array:
     """SwiGLU FFN. x: (..., D)."""
     return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
 
 
 def moe_ffn(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
-            axis_name: Optional[str] = None, axis_size: int = 1) -> jax.Array:
-    """MoE FFN over (B, S, D) activations.
-
-    When ``axis_name`` is given (inside shard_map), experts are sharded over
-    that axis: ``p``'s expert tensors are local slices and the result is
-    psummed.  Without it (CPU smoke tests), all experts are local.
-    ``axis_size`` must be the static mesh-axis size.
-    """
+            axis_name: str, axis_size: int) -> jax.Array:
+    """MoE FFN over (B, S, D) activations, inside shard_map: experts are
+    sharded over ``axis_name`` (static size ``axis_size``), ``p``'s expert
+    tensors are local slices and the result is psummed."""
     B, S, D = x.shape
-    xt = x.reshape(B * S, D)
-    m = cfg.moe
-    if axis_name is None:
-        out = moe_ffn_local(cfg, p, xt, 0, m.n_experts)
-    else:
-        e_local = m.n_experts // axis_size
-        e0 = jax.lax.axis_index(axis_name) * e_local
-        out = moe_ffn_local(cfg, p, xt, e0, e_local)
-        out = jax.lax.psum(out, axis_name)
-    return out.reshape(B, S, D)
+    e_local = cfg.moe.n_experts // axis_size
+    e0 = jax.lax.axis_index(axis_name) * e_local
+    out = moe_ffn_local(cfg, p, x.reshape(B * S, D), e0, e_local)
+    return jax.lax.psum(out, axis_name).reshape(B, S, D)

@@ -10,6 +10,9 @@ kinds inside the pattern.  Three entry points:
 
 ``ShardCtx`` carries mesh information; when present, activations get
 sharding constraints and MoE layers run expert-parallel under shard_map.
+Without it a MoE layer computes the experts it holds
+(``repro.models.moe.held_moe_ffn``), and ``prefill`` and ``decode_step``
+with ``counts=True`` also return its counters, summed over the layers.
 """
 
 from __future__ import annotations
@@ -26,7 +29,7 @@ from repro.models import recurrent as rec
 from repro.models.attention import (decode_attention, init_cache,
                                     prefill_attention)
 from repro.models.common import LayerSpec, ModelConfig, rms_norm
-from repro.models.moe import dense_ffn, moe_ffn
+from repro.models.moe import add_counts, dense_ffn, held_moe_ffn, moe_ffn
 from repro.models.scan_utils import maybe_scan
 
 
@@ -52,13 +55,21 @@ def _constrain(x: jax.Array, ctx: Optional[ShardCtx], spec) -> jax.Array:
 # Layer application (training / prefill form)
 # ---------------------------------------------------------------------------
 def _ffn_part(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
-              ctx: Optional[ShardCtx]) -> jax.Array:
+              ctx: Optional[ShardCtx]
+              ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """The FFN sublayer with its residual, and the MoE counters of a layer
+    of held experts (None for any other)."""
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    counts = None
     if cfg.moe is not None:
         if ctx is None:
-            y = moe_ffn(cfg, _moe_params(p), h)
+            B, S, D = h.shape
+            y, counts = held_moe_ffn(cfg, _moe_params(p), h.reshape(B * S, D))
+            y = y.reshape(B, S, D)
+        elif cfg.moe.held is not None:
+            raise ValueError(f"{cfg.name}: a share of held experts runs "
+                             f"without a mesh")
         else:
-            m = cfg.moe
             dp = ctx.dp_axes
             pspec_x = P(dp, None, None)
             especs = {
@@ -78,7 +89,7 @@ def _ffn_part(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
             y = fn(_moe_params(p), h)
     else:
         y = dense_ffn(p, h)
-    return x + y
+    return x + y, counts
 
 
 def _moe_params(p: Dict[str, Any]) -> Dict[str, Any]:
@@ -112,7 +123,7 @@ def apply_layer_train(cfg: ModelConfig, spec: LayerSpec, p: Dict[str, Any],
         attn_out, _ = prefill_attention(cfg, p, h, spec.window, positions,
                                         ctx=ctx)
         x = x + attn_out
-        x = _ffn_part(cfg, p, x, ctx)
+        x, _ = _ffn_part(cfg, p, x, ctx)
     elif spec.kind == "mlstm":
         x = rec.mlstm_block(cfg, p, x)
     elif spec.kind == "slstm":
@@ -120,7 +131,7 @@ def apply_layer_train(cfg: ModelConfig, spec: LayerSpec, p: Dict[str, Any],
     elif spec.kind == "rglru":
         x = rec.rglru_block(cfg, p, x)
         if spec.has_ffn:
-            x = _ffn_part(cfg, p, x, ctx)
+            x, _ = _ffn_part(cfg, p, x, ctx)
     else:
         raise ValueError(spec.kind)
     return x
@@ -247,56 +258,71 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int):
 
 def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p, x, cache,
                        position, ctx):
+    """One layer's decode step: (x, new state, MoE counters or None)."""
     p = _fsdp_gather(cfg, p, ctx)
     if spec.kind == "attn":
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         attn_out, new_cache = decode_attention(cfg, p, h, cache, position)
         x = x + attn_out
-        x = _ffn_part(cfg, p, x, ctx)
-        return x, new_cache
+        x, counts = _ffn_part(cfg, p, x, ctx)
+        return x, new_cache, counts
     if spec.kind == "mlstm":
-        return rec.mlstm_step(cfg, p, x, cache)
+        return (*rec.mlstm_step(cfg, p, x, cache), None)
     if spec.kind == "slstm":
-        return rec.slstm_step(cfg, p, x, cache)
+        return (*rec.slstm_step(cfg, p, x, cache), None)
     if spec.kind == "rglru":
         x, st = rec.rglru_step(cfg, p, x, cache)
+        counts = None
         if spec.has_ffn:
-            x = _ffn_part(cfg, p, x, ctx)
-        return x, st
+            x, counts = _ffn_part(cfg, p, x, ctx)
+        return x, st, counts
     raise ValueError(spec.kind)
 
 
 def decode_step(cfg: ModelConfig, params, caches, tokens: jax.Array,
-                position: jax.Array, ctx: Optional[ShardCtx] = None):
-    """tokens: (B,) int32; position: scalar int32. Returns (logits, caches)."""
+                position: jax.Array, ctx: Optional[ShardCtx] = None,
+                counts: bool = False):
+    """tokens: (B,) int32; position: scalar int32. Returns (logits, caches),
+    and with ``counts`` the MoE counters (int32[4] summed over the layers,
+    None for a model without held experts) after them."""
     x = embed(cfg, params, tokens[:, None], ctx)
     new_groups = []
+    total = None
     for gi, (pattern, reps) in enumerate(cfg.blocks):
         stacked = params["groups"][gi]
         caches_g = caches[gi]
 
         def body(xc, xs, pattern=pattern):
             layer_params, layer_caches = xs
-            new_lc = []
+            new_lc, k = [], None
             for spec, p, c in zip(pattern, layer_params, layer_caches):
-                xc, nc = apply_layer_decode(cfg, spec, p, xc, c, position, ctx)
+                xc, nc, kl = apply_layer_decode(cfg, spec, p, xc, c, position,
+                                                ctx)
                 new_lc.append(nc)
-            return xc, tuple(new_lc)
+                k = add_counts(k, kl)
+            return xc, (tuple(new_lc), k)
 
         if reps == 1:
-            x, ncs = body(x, (jax.tree.map(lambda a: a[0], stacked),
-                              jax.tree.map(lambda a: a[0], caches_g)))
+            x, (ncs, k) = body(x, (jax.tree.map(lambda a: a[0], stacked),
+                                   jax.tree.map(lambda a: a[0], caches_g)))
             ncs = jax.tree.map(lambda a: a[None], ncs)
         else:
-            x, ncs = maybe_scan(body, x, (stacked, caches_g), length=reps)
+            x, (ncs, k) = maybe_scan(body, x, (stacked, caches_g),
+                                     length=reps)
+            k = None if k is None else jnp.sum(k, axis=0)
         new_groups.append(ncs)
+        total = add_counts(total, k)
     logits = logits_fn(cfg, params, x, ctx)
+    if counts:
+        return logits[:, 0], tuple(new_groups), total
     return logits[:, 0], tuple(new_groups)
 
 
 def prefill(cfg: ModelConfig, params, inputs: jax.Array,
-            ctx: Optional[ShardCtx] = None, max_seq: Optional[int] = None):
-    """Run the full prompt, building caches.  Returns (last logits, caches).
+            ctx: Optional[ShardCtx] = None, max_seq: Optional[int] = None,
+            counts: bool = False):
+    """Run the full prompt, building caches.  Returns (last logits, caches),
+    and with ``counts`` the MoE counters as ``decode_step`` does.
 
     inputs: (B, S) tokens or (B, S, D) embeddings.
     """
@@ -305,29 +331,36 @@ def prefill(cfg: ModelConfig, params, inputs: jax.Array,
     x = embed(cfg, params, inputs, ctx)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     new_groups = []
+    total = None
     for gi, (pattern, reps) in enumerate(cfg.blocks):
         stacked = params["groups"][gi]
 
         def body(xc, layer_params, pattern=pattern):
-            new_lc = []
+            new_lc, k = [], None
             for spec, p in zip(pattern, layer_params):
-                xc, st = apply_layer_prefill(cfg, spec, p, xc, positions,
-                                             max_seq, ctx)
+                xc, st, kl = apply_layer_prefill(cfg, spec, p, xc, positions,
+                                                 max_seq, ctx)
                 new_lc.append(st)
-            return xc, tuple(new_lc)
+                k = add_counts(k, kl)
+            return xc, (tuple(new_lc), k)
 
         if reps == 1:
-            x, ncs = body(x, jax.tree.map(lambda a: a[0], stacked))
+            x, (ncs, k) = body(x, jax.tree.map(lambda a: a[0], stacked))
             ncs = jax.tree.map(lambda a: a[None], ncs)
         else:
-            x, ncs = maybe_scan(body, x, stacked, length=reps)
+            x, (ncs, k) = maybe_scan(body, x, stacked, length=reps)
+            k = None if k is None else jnp.sum(k, axis=0)
         new_groups.append(ncs)
+        total = add_counts(total, k)
     logits = logits_fn(cfg, params, x[:, -1:], ctx)
+    if counts:
+        return logits[:, 0], tuple(new_groups), total
     return logits[:, 0], tuple(new_groups)
 
 
 def apply_layer_prefill(cfg: ModelConfig, spec: LayerSpec, p, x, positions,
                         max_seq, ctx):
+    """One layer over the prompt: (x, state, MoE counters or None)."""
     p = _fsdp_gather(cfg, p, ctx)
     B, S = x.shape[:2]
     if spec.kind == "attn":
@@ -336,15 +369,16 @@ def apply_layer_prefill(cfg: ModelConfig, spec: LayerSpec, p, x, positions,
         attn_out, new_cache = prefill_attention(cfg, p, h, spec.window,
                                                 positions, cache, ctx=ctx)
         x = x + attn_out
-        x = _ffn_part(cfg, p, x, ctx)
-        return x, new_cache
+        x, counts = _ffn_part(cfg, p, x, ctx)
+        return x, new_cache, counts
     if spec.kind == "mlstm":
-        return rec.mlstm_block(cfg, p, x, return_state=True)
+        return (*rec.mlstm_block(cfg, p, x, return_state=True), None)
     if spec.kind == "slstm":
-        return rec.slstm_block(cfg, p, x, return_state=True)
+        return (*rec.slstm_block(cfg, p, x, return_state=True), None)
     if spec.kind == "rglru":
         x, st = rec.rglru_block(cfg, p, x, return_state=True)
+        counts = None
         if spec.has_ffn:
-            x = _ffn_part(cfg, p, x, ctx)
-        return x, st
+            x, counts = _ffn_part(cfg, p, x, ctx)
+        return x, st, counts
     raise ValueError(spec.kind)
