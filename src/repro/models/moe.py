@@ -3,12 +3,15 @@ parallelism over a mesh.
 
 Without a mesh (:func:`held_moe_ffn`) the layer holds the experts
 ``cfg.moe.held`` names, one expert-parallel share of the layer, or all of
-them.  It routes every token over all ``n_experts``, runs every held
-expert on every token (a capacity of T rows an expert, so no token is ever
-dropped) and weights each output by the token's routing weight, which is 0
-where the token is not routed to that expert.  What absent experts would
-add is left to the devices that hold them; nothing here stands in for
-them.  It also returns the counts of :data:`COUNTERS`.
+them.  It routes every token over all ``n_experts``.  A held expert to
+which the pass routes no token is neither read nor computed; each other
+held expert runs on every token of the pass (a capacity of T rows an
+expert, so no token is ever dropped), and its output is weighted by the
+token's routing weight, which is 0 where the token is not routed to it.
+A decode step's one token lands on half a held expert a layer on average,
+so the step reads few of the held experts' matrices.  What absent experts
+would add is left to the devices that hold them; nothing here stands in
+for them.  It also returns the counts of :data:`COUNTERS`.
 
 On a mesh (:func:`moe_ffn`), strategy "EP-as-TP": activations are
 replicated across the ``model`` mesh axis (as they already are for tensor parallelism); experts are
@@ -28,6 +31,7 @@ gather(mode="fill").
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -39,9 +43,11 @@ from repro.models.common import ModelConfig
 #: what :func:`held_moe_ffn` counts, in the order of its counts vector:
 #: (token, expert) pairs routed; of those, pairs on a held expert; held
 #: pairs that got no row (0: the layer is dropless); held experts that got
-#: at least one token
+#: at least one token; held experts whose weights the pass read
 COUNTERS = ("moe.routed_pairs", "moe.held_pairs", "moe.dropped",
-            "moe.held_experts_hit")
+            "moe.held_experts_hit", "moe.held_experts_read")
+#: an expert's matrices in a layer's parameters
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 #: the router's matmul precision: float32 logits, where a TPU's default
 #: for float32 operands is one bfloat16 pass, which can flip near-ties
 ROUTER_PRECISION = "highest"
@@ -122,26 +128,45 @@ def held_moe_ffn(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array
                  ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the layer over tokens x (T, D).
 
-    ``p``'s expert tensors hold the ``n`` experts of ``cfg.moe.held_range``
-    (n, D, F)/(n, F, D).  Returns that part (T, D) and the int32 counts of
-    :data:`COUNTERS`."""
+    ``p``'s expert tensors (:data:`EXPERT_WEIGHTS`) hold the ``n`` experts
+    of ``cfg.moe.held_range``, (n, D, F)/(n, F, D); or, where ``p["layer"]``
+    is a layer's index, the stack of every layer's, (L, n, D, F)/
+    (L, n, F, D), out of which each expert's matrices are sliced where they
+    are used, so that no layer's share is copied whole.  Only the held
+    experts to which some token is routed run, in ascending order, each
+    adding its gate times its SwiGLU into a float32 sum.  Returns that part
+    (T, D) and the int32 counts of :data:`COUNTERS`."""
     m = cfg.moe
     e0, n = m.held_range
     T = x.shape[0]
+    layer = p.get("layer")
+    w = [p[k] if layer is not None else p[k][None] for k in EXPERT_WEIGHTS]
+    layer = 0 if layer is None else layer
     with jax.named_scope("moe.route"):
         top_w, top_e = route(cfg, p["router"], x)                # (T, k)
         hit = top_e[..., None] == e0 + jnp.arange(n)             # (T, k, n)
         gates = jnp.sum(jnp.where(hit, top_w[..., None], 0.0), axis=1)
+        gates = gates.astype(x.dtype)
         rows = jnp.sum(hit, axis=(0, 1))                 # pairs per expert
+
+    def expert(e, carry):
+        acc, read = carry
+        wg, wu, wd = (jax.lax.dynamic_slice(a, (layer, e, 0, 0),
+                                            (1, 1) + a.shape[2:])[0, 0]
+                      for a in w)
+        y = (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+        return acc + gates[:, e, None].astype(jnp.float32) * y, read + 1
+
     with jax.named_scope("moe.experts"):
-        h = jnp.einsum("td,edf->etf", x, p["w_gate"])
-        u = jnp.einsum("td,edf->etf", x, p["w_up"])
-        y = jnp.einsum("etf,efd->etd", jax.nn.silu(h) * u, p["w_down"])
-        out = jnp.einsum("te,etd->td", gates.astype(x.dtype), y)
+        carry = (jnp.zeros(x.shape, jnp.float32), jnp.int32(0))
+        for e in range(n):
+            carry = jax.lax.cond(rows[e] > 0, functools.partial(expert, e),
+                                 lambda c: c, carry)
+        out, read = carry
     counts = jnp.stack([jnp.int32(T * m.top_k), jnp.sum(rows),
                         jnp.sum(jnp.maximum(rows - T, 0)),
-                        jnp.sum(rows > 0)]).astype(jnp.int32)
-    return out, counts
+                        jnp.sum(rows > 0), read]).astype(jnp.int32)
+    return out.astype(x.dtype), counts
 
 
 def dense_ffn(p: Dict[str, Any], x: jax.Array) -> jax.Array:

@@ -13,6 +13,9 @@ sharding constraints and MoE layers run expert-parallel under shard_map.
 Without it a MoE layer computes the experts it holds
 (``repro.models.moe.held_moe_ffn``), and ``prefill`` and ``decode_step``
 with ``counts=True`` also return its counters, summed over the layers.
+Their layer scans leave the held experts' weights stacked and hand each
+layer its index, so that the expert layer slices out only the experts it
+runs.
 """
 
 from __future__ import annotations
@@ -29,7 +32,8 @@ from repro.models import recurrent as rec
 from repro.models.attention import (decode_attention, init_cache,
                                     prefill_attention)
 from repro.models.common import LayerSpec, ModelConfig, rms_norm
-from repro.models.moe import add_counts, dense_ffn, held_moe_ffn, moe_ffn
+from repro.models.moe import (EXPERT_WEIGHTS, add_counts, dense_ffn,
+                              held_moe_ffn, moe_ffn)
 from repro.models.scan_utils import maybe_scan
 
 
@@ -93,7 +97,7 @@ def _ffn_part(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
 
 
 def _moe_params(p: Dict[str, Any]) -> Dict[str, Any]:
-    return {k: p[k] for k in ("router", "w_gate", "w_up", "w_down")}
+    return {k: p[k] for k in ("router", *EXPERT_WEIGHTS, "layer") if k in p}
 
 
 def _fsdp_gather(cfg: ModelConfig, p: Dict[str, Any],
@@ -279,21 +283,49 @@ def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p, x, cache,
     raise ValueError(spec.kind)
 
 
+def _over_layers(cfg: ModelConfig, ctx: Optional[ShardCtx], body, x,
+                 stacked, states, reps: int):
+    """``body(x, layer_params, layer_states) -> (x, (new states, counts))``
+    over a group's ``reps`` layers: returns x, the stacked new states and
+    the counts summed over the layers.  ``stacked`` and ``states`` are the
+    group's per-layer stacks.  A layer of held experts (no mesh) gets those
+    experts' weights whole, with its index under ``"layer"``: a layer's
+    slice of them would be copied where the expert layer runs an expert
+    only if it is routed to."""
+    experts = None
+    if cfg.moe is not None and ctx is None:
+        experts = tuple({k: p[k] for k in EXPERT_WEIGHTS if k in p}
+                        for p in stacked)
+        stacked = tuple({k: v for k, v in p.items() if k not in EXPERT_WEIGHTS}
+                        for p in stacked)
+
+    def step(xc, xs):
+        layer_params, layer_states, i = xs
+        if experts is not None:
+            layer_params = tuple(dict(p, layer=i, **e) if e else p
+                                 for p, e in zip(layer_params, experts))
+        return body(xc, layer_params, layer_states)
+
+    if reps == 1:
+        first = jax.tree.map(lambda a: a[0], (stacked, states))
+        x, (ncs, k) = step(x, first + (0,))
+        return x, jax.tree.map(lambda a: a[None], ncs), k
+    index = None if experts is None else jnp.arange(reps)
+    x, (ncs, k) = maybe_scan(step, x, (stacked, states, index), length=reps)
+    return x, ncs, None if k is None else jnp.sum(k, axis=0)
+
+
 def decode_step(cfg: ModelConfig, params, caches, tokens: jax.Array,
                 position: jax.Array, ctx: Optional[ShardCtx] = None,
                 counts: bool = False):
     """tokens: (B,) int32; position: scalar int32. Returns (logits, caches),
-    and with ``counts`` the MoE counters (int32[4] summed over the layers,
-    None for a model without held experts) after them."""
+    and with ``counts`` the MoE counters (int32[len(moe.COUNTERS)] summed
+    over the layers, None for a model without held experts) after them."""
     x = embed(cfg, params, tokens[:, None], ctx)
     new_groups = []
     total = None
     for gi, (pattern, reps) in enumerate(cfg.blocks):
-        stacked = params["groups"][gi]
-        caches_g = caches[gi]
-
-        def body(xc, xs, pattern=pattern):
-            layer_params, layer_caches = xs
+        def body(xc, layer_params, layer_caches, pattern=pattern):
             new_lc, k = [], None
             for spec, p, c in zip(pattern, layer_params, layer_caches):
                 xc, nc, kl = apply_layer_decode(cfg, spec, p, xc, c, position,
@@ -302,14 +334,8 @@ def decode_step(cfg: ModelConfig, params, caches, tokens: jax.Array,
                 k = add_counts(k, kl)
             return xc, (tuple(new_lc), k)
 
-        if reps == 1:
-            x, (ncs, k) = body(x, (jax.tree.map(lambda a: a[0], stacked),
-                                   jax.tree.map(lambda a: a[0], caches_g)))
-            ncs = jax.tree.map(lambda a: a[None], ncs)
-        else:
-            x, (ncs, k) = maybe_scan(body, x, (stacked, caches_g),
-                                     length=reps)
-            k = None if k is None else jnp.sum(k, axis=0)
+        x, ncs, k = _over_layers(cfg, ctx, body, x, params["groups"][gi],
+                                 caches[gi], reps)
         new_groups.append(ncs)
         total = add_counts(total, k)
     logits = logits_fn(cfg, params, x, ctx)
@@ -333,9 +359,7 @@ def prefill(cfg: ModelConfig, params, inputs: jax.Array,
     new_groups = []
     total = None
     for gi, (pattern, reps) in enumerate(cfg.blocks):
-        stacked = params["groups"][gi]
-
-        def body(xc, layer_params, pattern=pattern):
+        def body(xc, layer_params, _, pattern=pattern):
             new_lc, k = [], None
             for spec, p in zip(pattern, layer_params):
                 xc, st, kl = apply_layer_prefill(cfg, spec, p, xc, positions,
@@ -344,12 +368,8 @@ def prefill(cfg: ModelConfig, params, inputs: jax.Array,
                 k = add_counts(k, kl)
             return xc, (tuple(new_lc), k)
 
-        if reps == 1:
-            x, (ncs, k) = body(x, jax.tree.map(lambda a: a[0], stacked))
-            ncs = jax.tree.map(lambda a: a[None], ncs)
-        else:
-            x, (ncs, k) = maybe_scan(body, x, stacked, length=reps)
-            k = None if k is None else jnp.sum(k, axis=0)
+        x, ncs, k = _over_layers(cfg, ctx, body, x, params["groups"][gi],
+                                 None, reps)
         new_groups.append(ncs)
         total = add_counts(total, k)
     logits = logits_fn(cfg, params, x[:, -1:], ctx)
