@@ -1,7 +1,6 @@
 """Benchmark driver: one module per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows.  The roofline benchmark reads
-the dry-run artifacts (artifacts/dryrun/*.json) when present.
+Prints ``name,us_per_call,derived`` CSV rows.
 
 ``--json`` additionally writes the repo's perf-trajectory artifacts:
 
@@ -55,7 +54,7 @@ def main() -> None:
                             fig9_breakdown, fig10_nonequivocation,
                             fig11_reconfig, fig11_tail_latency, inference,
                             selfheal, sharded, shared_pools, table2_memory,
-                            throughput, roofline)
+                            throughput)
     mods = {
         "fig7": fig7_app_latency,
         "fig8": fig8_request_size,
@@ -70,7 +69,6 @@ def main() -> None:
         "selfheal": selfheal,
         "inference": inference,
         "engine": engine_perf,
-        "roofline": roofline,
     }
     args = sys.argv[1:]
     want_json = "--json" in args

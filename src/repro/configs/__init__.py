@@ -1,6 +1,4 @@
-from repro.configs.registry import (ARCHS, LONG_CONTEXT_OK,
-                                    get_config, get_smoke_config,
+from repro.configs.registry import (ARCHS, get_config, get_smoke_config,
                                     list_archs)
 
-__all__ = ["ARCHS", "LONG_CONTEXT_OK", "get_config",
-           "get_smoke_config", "list_archs"]
+__all__ = ["ARCHS", "get_config", "get_smoke_config", "list_archs"]

@@ -20,9 +20,6 @@ ARCHS: Dict[str, str] = {
     "recurrentgemma-2b": "repro.configs.recurrentgemma_2b",
 }
 
-#: archs with a sub-quadratic (or state-based) path for long_500k decode
-LONG_CONTEXT_OK = {"gemma3-4b", "gemma3-1b", "xlstm-1.3b", "recurrentgemma-2b"}
-
 
 def list_archs() -> List[str]:
     return sorted(ARCHS)

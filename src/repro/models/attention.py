@@ -6,8 +6,8 @@ Three execution paths, chosen by shape and window:
   window size and each query chunk attends to exactly two key chunks (its own
   and the previous one) gathered into a banded tensor — one einsum, no scan,
   true O(S·w) FLOPs.  This is the pure-JAX analog of the Pallas
-  sliding-window kernel in ``repro.kernels.swa`` (the dry-run compiles this
-  path; the kernel is the TPU-target implementation).
+  sliding-window kernel in ``repro.kernels.swa`` (the models run this path;
+  the kernel is on no model's path yet).
 * **chunked-full** (global layers, train/prefill): scan over query chunks,
   full einsum against all keys per chunk — O(S²) FLOPs, O(S·chunk) memory.
 * **decode**: one query token against a KV cache; window layers use a ring
@@ -189,35 +189,14 @@ def decode_attention(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
 
 def prefill_attention(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
                       window: Optional[int], positions: jax.Array,
-                      cache: Optional[Dict[str, jax.Array]] = None,
-                      ctx=None) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """Training/prefill attention; fills the cache if given.
-
-    With ``cfg.attn_head_shard`` (§Perf #2): K/V are expanded to H heads and
-    q/k/v constrained head-sharded on the TP axis, so every attention einsum
-    contracts only local dims — replacing per-chunk fp32-score all-reduces
-    with the single standard TP all-reduce after the output projection.
-    """
+                      cache: Optional[Dict[str, jax.Array]] = None
+                      ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """Training/prefill attention; fills the cache if given."""
     q, k, v = qkv_project(cfg, p, x, positions)
-    k_store, v_store = k, v
-    if ctx is not None and cfg.attn_head_shard:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        tp = ctx.mesh.shape[ctx.tp_axis]
-        if cfg.n_heads % tp == 0:
-            G = cfg.n_heads // cfg.n_kv_heads
-            if G > 1:
-                k = jnp.repeat(k, G, axis=2)
-                v = jnp.repeat(v, G, axis=2)
-            spec = NamedSharding(ctx.mesh,
-                                 P(ctx.dp_axes, None, ctx.tp_axis, None))
-            q = jax.lax.with_sharding_constraint(q, spec)
-            k = jax.lax.with_sharding_constraint(k, spec)
-            v = jax.lax.with_sharding_constraint(v, spec)
     out = attention_train(cfg, q, k, v, window)
     B, S = x.shape[:2]
     new_cache = None
     if cache is not None:
-        k, v = k_store, v_store
         size = cache["k"].shape[1]
         if size >= S:
             new_cache = {

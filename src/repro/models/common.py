@@ -4,8 +4,8 @@ Every assigned architecture is described by a :class:`ModelConfig` whose
 ``blocks`` field is a *pattern program*: a list of (pattern, repeats) groups,
 where a pattern is a tuple of :class:`LayerSpec`s.  The forward pass scans
 over ``repeats`` within each group (one compiled body per group), which keeps
-HLO size O(#distinct layer kinds) instead of O(#layers) — essential for the
-512-device dry-run compiles — while supporting heterogeneous stacks
+HLO size O(#distinct layer kinds) instead of O(#layers), and so the compile
+short, while supporting heterogeneous stacks
 (gemma3's 5:1 local:global, recurrentgemma's 1:2 RG-LRU:attention,
 xLSTM's mLSTM/sLSTM alternation).
 """
@@ -38,7 +38,6 @@ class MoEConfig:
     top_k: int
     d_expert: int
     capacity_factor: float = 1.25   # mesh path only (repro.models.moe)
-    router_dtype: str = "float32"
     #: (first, count): the experts this device holds, one expert-parallel
     #: share of the layer; None holds all ``n_experts``
     held: Optional[Tuple[int, int]] = None
@@ -69,16 +68,10 @@ class ModelConfig:
     dtype: str = "bfloat16"
     # modality frontend stub: inputs are precomputed embeddings, not token ids
     frontend: Optional[str] = None   # None | "audio" | "vlm"
-    max_seq: int = 131_072
-    # --- runtime / performance knobs (hillclimbed in §Perf) ---
-    remat: str = "full"          # "none" | "dots" | "full"
+    # --- runtime knobs ---
+    remat: str = "full"          # "none" | "full"
     q_chunk: int = 512
-    kv_chunk: int = 1024
     mlstm_chunk: int = 256
-    logits_fp32: bool = False
-    attest: bool = True          # fingerprint grads/params each step (uBFT)
-    fsdp_gather: bool = False    # ZeRO-3 per-layer weight gather (§Perf #1)
-    attn_head_shard: bool = False  # expand KV to H heads + shard heads (§Perf #2)
 
     @property
     def dh(self) -> int:

@@ -20,8 +20,7 @@ sharded across it.  Every device routes the full local token set, computes
 (no (T, E, C) one-hot — O(T·k) memory), and the contributions are combined
 with the same all-reduce that tensor parallelism already pays.  For top-k≥2
 this moves strictly fewer bytes than a token all-to-all (2·D vs k·D per
-token) and composes with XLA's collective fusion; the all-to-all variant is
-kept as a hillclimb alternative (see EXPERIMENTS.md §Perf).
+token) and composes with XLA's collective fusion.
 
 The mesh path's routed computation is ragged; it uses fixed per-expert
 capacity C = max(min_cap, ceil(T·k/E · capacity_factor)) with token dropping

@@ -15,13 +15,12 @@ Decode forms: single-step state updates; state replaces the KV cache.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
 from repro.models.common import ModelConfig, rms_norm
-from repro.models.scan_utils import maybe_scan
 
 
 # ---------------------------------------------------------------------------
@@ -110,7 +109,7 @@ def mlstm_train(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
     xs = (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0),
           jnp.moveaxis(itc, 1, 0), jnp.moveaxis(csum_f, 1, 0),
           jnp.moveaxis(total_f, 1, 0))
-    (Cf, nf, mf), hs = maybe_scan(body, (C0, n0, m0), xs, length=nc)
+    (Cf, nf, mf), hs = jax.lax.scan(body, (C0, n0, m0), xs, length=nc)
     h = jnp.moveaxis(hs, 0, 1).reshape(B, Sp, H * dh)[:, :S]
     return h, {"C": Cf, "n": nf, "m": mf}
 
@@ -197,7 +196,7 @@ def slstm_block(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
     m0 = jnp.zeros((B, H, dh), jnp.float32)
     xs = (jnp.moveaxis(zx, 1, 0), jnp.moveaxis(ix, 1, 0),
           jnp.moveaxis(fx, 1, 0), jnp.moveaxis(ox, 1, 0))
-    (cf, hf, mf), hs = maybe_scan(step, (c0, h0, m0), xs, length=S)
+    (cf, hf, mf), hs = jax.lax.scan(step, (c0, h0, m0), xs, length=S)
     h = jnp.moveaxis(hs, 0, 1).reshape(B, S, D)
     y = h @ p["wo"]
     u, g = jnp.split(hin @ p["up"], 2, axis=-1)

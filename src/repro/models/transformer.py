@@ -34,13 +34,12 @@ from repro.models.attention import (decode_attention, init_cache,
 from repro.models.common import LayerSpec, ModelConfig, rms_norm
 from repro.models.moe import (EXPERT_WEIGHTS, add_counts, dense_ffn,
                               held_moe_ffn, moe_ffn)
-from repro.models.scan_utils import maybe_scan
 
 
 @dataclass(frozen=True)
 class ShardCtx:
     mesh: Mesh
-    dp_axes: Tuple[str, ...] = ("data",)     # batch axes (may include "pod")
+    dp_axes: Tuple[str, ...] = ("data",)     # batch axes
     tp_axis: str = "model"
 
     @property
@@ -100,32 +99,12 @@ def _moe_params(p: Dict[str, Any]) -> Dict[str, Any]:
     return {k: p[k] for k in ("router", *EXPERT_WEIGHTS, "layer") if k in p}
 
 
-def _fsdp_gather(cfg: ModelConfig, p: Dict[str, Any],
-                 ctx: Optional[ShardCtx]) -> Dict[str, Any]:
-    """§Perf hillclimb #1: constrain weights to their FSDP-axis-free spec so
-    XLA gathers the (small) weights per layer instead of all-reducing the
-    (large) partial activations."""
-    if ctx is None or not cfg.fsdp_gather:
-        return p
-    from repro.parallel.sharding import weight_compute_spec
-    out = {}
-    for k, v in p.items():
-        if hasattr(v, "ndim") and v.ndim >= 2:
-            out[k] = _constrain(v, ctx, weight_compute_spec(k, v.shape,
-                                                            ctx.mesh))
-        else:
-            out[k] = v
-    return out
-
-
 def apply_layer_train(cfg: ModelConfig, spec: LayerSpec, p: Dict[str, Any],
                       x: jax.Array, positions: jax.Array,
                       ctx: Optional[ShardCtx]) -> jax.Array:
-    p = _fsdp_gather(cfg, p, ctx)
     if spec.kind == "attn":
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        attn_out, _ = prefill_attention(cfg, p, h, spec.window, positions,
-                                        ctx=ctx)
+        attn_out, _ = prefill_attention(cfg, p, h, spec.window, positions)
         x = x + attn_out
         x, _ = _ffn_part(cfg, p, x, ctx)
     elif spec.kind == "mlstm":
@@ -144,10 +123,7 @@ def apply_layer_train(cfg: ModelConfig, spec: LayerSpec, p: Dict[str, Any],
 def _remat(cfg: ModelConfig, fn):
     if cfg.remat == "none":
         return fn
-    if cfg.remat == "full":
-        return jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable)
-    return jax.checkpoint(
-        fn, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
+    return jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable)
 
 
 def apply_groups_train(cfg: ModelConfig, params: Dict[str, Any], x: jax.Array,
@@ -160,11 +136,11 @@ def apply_groups_train(cfg: ModelConfig, params: Dict[str, Any], x: jax.Array,
                 xc = apply_layer_train(cfg, spec, p, xc, positions, ctx)
             return xc, None
 
-        body = _remat(cfg, body) if cfg.remat != "none" else body
+        body = _remat(cfg, body)
         if reps == 1:
             x, _ = body(x, jax.tree.map(lambda a: a[0], stacked))
         else:
-            x, _ = maybe_scan(body, x, stacked, length=reps)
+            x, _ = jax.lax.scan(body, x, stacked, length=reps)
     return x
 
 
@@ -174,13 +150,7 @@ def apply_groups_train(cfg: ModelConfig, params: Dict[str, Any], x: jax.Array,
 def embed(cfg: ModelConfig, params, tokens_or_embeds: jax.Array,
           ctx: Optional[ShardCtx]) -> jax.Array:
     if tokens_or_embeds.dtype in (jnp.int32, jnp.int64):
-        table = params["embed"]
-        if ctx is not None and cfg.fsdp_gather:
-            from repro.parallel.sharding import weight_compute_spec
-            table = _constrain(table, ctx,
-                               weight_compute_spec("embed", table.shape,
-                                                   ctx.mesh))
-        x = table[tokens_or_embeds] * (cfg.d_model ** 0.5)
+        x = params["embed"][tokens_or_embeds] * (cfg.d_model ** 0.5)
         x = x.astype(cfg.jdtype())
     else:
         x = tokens_or_embeds.astype(cfg.jdtype())   # frontend stub: embeddings
@@ -194,15 +164,8 @@ def logits_fn(cfg: ModelConfig, params, x: jax.Array,
     x = rms_norm(x, params["out_norm"], cfg.norm_eps)
     head = params.get("lm_head")
     if head is None:
-        emb = params["embed"]
-        if ctx is not None and cfg.fsdp_gather:
-            from repro.parallel.sharding import weight_compute_spec
-            emb = _constrain(emb, ctx,
-                             weight_compute_spec("embed", emb.shape, ctx.mesh))
-        head = emb.T
+        head = params["embed"].T
     logits = x @ head.astype(x.dtype)
-    if cfg.logits_fp32:
-        logits = logits.astype(jnp.float32)
     if ctx is not None:
         logits = _constrain(logits, ctx, P(ctx.dp_axes, None, ctx.tp_axis))
     return logits
@@ -263,7 +226,6 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int):
 def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p, x, cache,
                        position, ctx):
     """One layer's decode step: (x, new state, MoE counters or None)."""
-    p = _fsdp_gather(cfg, p, ctx)
     if spec.kind == "attn":
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         attn_out, new_cache = decode_attention(cfg, p, h, cache, position)
@@ -311,7 +273,7 @@ def _over_layers(cfg: ModelConfig, ctx: Optional[ShardCtx], body, x,
         x, (ncs, k) = step(x, first + (0,))
         return x, jax.tree.map(lambda a: a[None], ncs), k
     index = None if experts is None else jnp.arange(reps)
-    x, (ncs, k) = maybe_scan(step, x, (stacked, states, index), length=reps)
+    x, (ncs, k) = jax.lax.scan(step, x, (stacked, states, index), length=reps)
     return x, ncs, None if k is None else jnp.sum(k, axis=0)
 
 
@@ -381,13 +343,12 @@ def prefill(cfg: ModelConfig, params, inputs: jax.Array,
 def apply_layer_prefill(cfg: ModelConfig, spec: LayerSpec, p, x, positions,
                         max_seq, ctx):
     """One layer over the prompt: (x, state, MoE counters or None)."""
-    p = _fsdp_gather(cfg, p, ctx)
     B, S = x.shape[:2]
     if spec.kind == "attn":
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         cache = init_cache(cfg, spec.window, B, max_seq, cfg.jdtype())
         attn_out, new_cache = prefill_attention(cfg, p, h, spec.window,
-                                                positions, cache, ctx=ctx)
+                                                positions, cache)
         x = x + attn_out
         x, counts = _ffn_part(cfg, p, x, ctx)
         return x, new_cache, counts

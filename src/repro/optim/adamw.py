@@ -97,14 +97,3 @@ def adamw_update(params: Any, grads: Any, state: Dict[str, Any],
         "count": count,
     }
     return new_params, new_state
-
-
-def opt_pspecs(param_specs: Any) -> Dict[str, Any]:
-    """Optimizer-state PartitionSpecs mirroring the parameter specs."""
-    from jax.sharding import PartitionSpec as P
-    return {
-        "mu": param_specs,
-        "nu": param_specs,
-        "master": param_specs,
-        "count": P(),
-    }

@@ -1,4 +1,4 @@
-from repro.parallel.sharding import (batch_pspecs, cache_pspecs,
-                                     param_pspecs, shard_ctx_for_mesh)
+from repro.parallel.sharding import (batch_pspecs, param_pspecs,
+                                     shard_ctx_for_mesh)
 
-__all__ = ["param_pspecs", "batch_pspecs", "cache_pspecs", "shard_ctx_for_mesh"]
+__all__ = ["param_pspecs", "batch_pspecs", "shard_ctx_for_mesh"]

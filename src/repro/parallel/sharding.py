@@ -1,19 +1,16 @@
-"""Sharding rules: parameter / batch / cache PartitionSpecs per mesh.
+"""Sharding rules: parameter / batch PartitionSpecs per mesh.
 
 Axes:
-  pod    — data parallelism across pods (multi-pod mesh only)
   data   — data parallelism + FSDP (params' non-model dim sharded here)
-  model  — tensor parallelism: heads / FFN / experts / vocab; also the
-           sequence axis of decode KV caches (flash-decode style)
+  model  — tensor parallelism: heads / FFN / experts / vocab
 
-The rules are name-based over the parameter tree.  Stacked layer-group
-params get a leading ``None`` axis.  These rules are the *baseline*
-(paper-faithful DP+TP+EP+FSDP); §Perf hillclimbs deviations per cell.
+The rules (DP+TP+EP+FSDP) are name-based over the parameter tree.  Stacked
+layer-group params get a leading ``None`` axis.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import numpy as np
@@ -26,9 +23,12 @@ FSDP = "data"
 TP = "model"
 
 
+def _dp_axes(mesh: Mesh) -> Tuple[str, ...]:
+    return (FSDP,) if FSDP in mesh.axis_names else ()
+
+
 def shard_ctx_for_mesh(mesh: Mesh) -> ShardCtx:
-    dp_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    return ShardCtx(mesh=mesh, dp_axes=dp_axes, tp_axis=TP)
+    return ShardCtx(mesh=mesh, dp_axes=_dp_axes(mesh), tp_axis=TP)
 
 
 def _rule_for(name: str, shape: Tuple[int, ...], cfg: ModelConfig,
@@ -79,16 +79,6 @@ def _divisible(spec: P, shape: Tuple[int, ...], mesh: Mesh) -> P:
     return P(*fixed)
 
 
-def weight_compute_spec(name: str, shape: Tuple[int, ...], mesh: Mesh) -> P:
-    """Compute-time spec for a weight: the storage rule with the FSDP axis
-    dropped (ZeRO-3 style per-layer gather — constraining a weight to this
-    spec makes XLA all-gather the small weight over ``data`` instead of
-    all-reducing the large activations)."""
-    spec = _rule_for(name, shape, None, stacked=False)
-    fixed = tuple(None if ax == FSDP else ax for ax in tuple(spec))
-    return _divisible(P(*fixed), shape, mesh)
-
-
 def param_pspecs(cfg: ModelConfig, params: Any, mesh: Mesh) -> Any:
     """PartitionSpec pytree matching ``params``."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
@@ -106,43 +96,8 @@ def param_pspecs(cfg: ModelConfig, params: Any, mesh: Mesh) -> Any:
 
 
 def batch_pspecs(cfg: ModelConfig, mesh: Mesh) -> Dict[str, P]:
-    dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    dp = _dp_axes(mesh)
     return {"inputs": P(dp), "targets": P(dp)}
-
-
-def cache_pspecs(cfg: ModelConfig, caches: Any, mesh: Mesh,
-                 seq_shard: bool = True) -> Any:
-    """Decode caches: batch over dp; KV-cache sequence axis over `model`
-    (flash-decode / context-parallel decode) when divisible."""
-    dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-
-    def rule(path, leaf):
-        name = None
-        for entry in reversed(path):
-            if hasattr(entry, "key"):
-                name = entry.key
-                break
-        shape = leaf.shape
-        # stacked leading reps dim, then batch
-        if name in ("k", "v"):      # (R, B, S, KV, dh)
-            spec = P(None, dp, TP if seq_shard else None, None, None)
-        elif name == "pos":         # (R, S)
-            spec = P(None, TP if seq_shard else None)
-        elif name in ("C",):        # (R, B, H, dh, dh)
-            spec = P(None, dp, None, None, None)
-        elif name in ("n", "c", "h", "m"):   # (R, B, H, dh) / (R, B, H)
-            spec = P(*( (None, dp) + (None,) * (len(shape) - 2) ))
-        elif name == "y":           # (R, B, W)
-            spec = P(None, dp, TP)
-        elif name == "conv":        # (R, B, 3, W)
-            spec = P(None, dp, None, TP)
-        else:
-            spec = P(*(None,) * len(shape))
-        return _divisible(spec, shape, mesh)
-
-    flat, treedef = jax.tree_util.tree_flatten_with_path(caches)
-    return jax.tree_util.tree_unflatten(
-        treedef, [rule(p, l) for p, l in flat])
 
 
 def named(mesh: Mesh, spec_tree: Any) -> Any:

@@ -1,21 +1,17 @@
-"""Step builders: the functions the launcher jits/lowers.
+"""The training step the launcher jits: fwd + bwd + AdamW + the uBFT
+attestation fingerprints of the gradients and the new parameters.
 
-  make_train_step(cfg, ctx)  — fwd + bwd + AdamW + attestation fingerprints
-  make_prefill(cfg, ctx)     — prompt ingestion, returns last logits + caches
-  make_serve_step(cfg, ctx)  — one decode token against caches/state
+  make_train_step(cfg, ctx, opt_cfg)
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.models.common import ModelConfig
-from repro.models.transformer import (ShardCtx, decode_step, lm_loss,
-                                      prefill)
+from repro.models.transformer import ShardCtx, lm_loss
 from repro.optim.adamw import AdamWConfig, adamw_update
 from repro.runtime.attest import fingerprint_tree
 
@@ -29,26 +25,10 @@ def make_train_step(cfg: ModelConfig, ctx: Optional[ShardCtx] = None,
             lambda p: lm_loss(cfg, p, batch["inputs"], batch["targets"], ctx)
         )(params)
         new_params, new_opt = adamw_update(params, grads, opt_state, opt_cfg)
-        metrics = {"loss": loss}
-        if cfg.attest:
-            # uBFT attestation: replicas CTBcast these (see repro.runtime.trainer)
-            metrics["grad_fp"] = fingerprint_tree(grads)
-            metrics["param_fp"] = fingerprint_tree(new_params)
+        # uBFT attestation: replicas CTBcast these (see repro.runtime.trainer)
+        metrics = {"loss": loss,
+                   "grad_fp": fingerprint_tree(grads),
+                   "param_fp": fingerprint_tree(new_params)}
         return new_params, new_opt, metrics
 
     return train_step
-
-
-def make_prefill(cfg: ModelConfig, ctx: Optional[ShardCtx] = None,
-                 max_seq: Optional[int] = None):
-    def prefill_step(params, inputs):
-        return prefill(cfg, params, inputs, ctx, max_seq=max_seq)
-
-    return prefill_step
-
-
-def make_serve_step(cfg: ModelConfig, ctx: Optional[ShardCtx] = None):
-    def serve_step(params, caches, tokens, position):
-        return decode_step(cfg, params, caches, tokens, position, ctx)
-
-    return serve_step

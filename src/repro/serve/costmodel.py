@@ -1,10 +1,10 @@
 """Roofline serving-cost model: per-token decode / prefill time in µs.
 
 Bridges the repo's two halves: the JAX serving stack knows what a model
-costs per token (``benchmarks/roofline.py``'s three-term roofline over
-``configs/registry.py`` architectures), and the consensus plane now has
-a deferred execution engine (``App.cost_us``) that charges deterministic
-service time per decided request.  This module turns an architecture
+costs per token (a roofline over ``configs/registry.py`` architectures),
+and the consensus plane now has a deferred execution engine
+(``App.cost_us``) that charges deterministic service time per decided
+request.  This module turns an architecture
 into that charge.
 
 The decode roofline (one token for each of B batched streams):
@@ -15,9 +15,8 @@ The decode roofline (one token for each of B batched streams):
 Small-batch decode is HBM-bound on reading the weights, so per-token
 cost ≈ param_bytes / (HBM_BW·B) — the classic batching amortization.
 Prefill is charged as one compute-bound pass over the prompt, amortized
-across the same serving batch.  Constants match
-``benchmarks/roofline.py`` (TPU-class chip: 197 TFLOP/s bf16, 819 GB/s
-HBM).
+across the same serving batch.  Constants are one TPU v5e's
+(197 TFLOP/s bf16, 819 GB/s HBM), as in ``bench/harness/peaks.json``.
 
 ``from_arch`` derives the parameter/KV byte counts analytically from a
 :class:`repro.models.common.ModelConfig` (attention stacks with dense or
@@ -31,7 +30,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-# benchmarks/roofline.py's chip model
+# one TPU v5e (a copy of bench/harness/peaks.json)
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 

@@ -3,8 +3,8 @@ import sys
 
 import pytest
 
-# Tests and benches see ONE device; only the dry-run forces 512 (and sets its
-# own XLA_FLAGS before any jax import — see repro/launch/dryrun.py).
+# Tests and benches see ONE device; the mesh tests run in subprocesses that
+# set their own XLA_FLAGS for virtual CPU devices before any jax import.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 

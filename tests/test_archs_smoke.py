@@ -57,8 +57,7 @@ def test_smoke_train_step(arch):
                                            0, cfg.vocab)}
     new_params, new_opt, metrics = step(params, opt, batch)
     assert np.isfinite(float(metrics["loss"]))
-    if cfg.attest:
-        assert int(metrics["grad_fp"]) != 0
+    assert int(metrics["grad_fp"]) != 0
     # params actually moved
     moved = any(
         not np.allclose(np.asarray(a, np.float32), np.asarray(b, np.float32))
